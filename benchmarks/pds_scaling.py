@@ -49,4 +49,4 @@ def run(full: bool = False):
         if rec.get("status") == "OK":
             emit(f"miniexp3/pq_step/{rec['mesh']}", 0.0,
                  f"coll_bytes={rec['collectives'].get('total', 0):.3e};"
-                 f"dot_flops={rec['dot_flops']:.3e}")
+                 f"devices={rec['n_devices']}")

@@ -3,19 +3,23 @@
     PYTHONPATH=src python -m benchmarks.run [--full] [--only fig8,...]
 
 Prints ``name,us_per_call,derived`` CSV rows (also saved to
-results/bench.csv).  Default is the quick profile (~10 min on one CPU
-core); --full runs the paper-scale sweeps.
+results/bench.csv).  A module that raises is reported as an ``ERROR:``
+row, the others still run, and the harness then exits 1.  Default is
+the quick profile (~10 min on one CPU core); --full runs the
+paper-scale sweeps.
 """
 import argparse
 import os
+import sys
 import time
 
 # Give the CPU host virtual devices BEFORE jax first initializes so the
 # distributed-pricing section of appc_warm_start runs on a real multi-device
 # mesh (no-op when XLA_FLAGS already pins a device count, e.g. on TPU).
-from repro.hostdev import ensure_host_devices
+from repro.hostdev import ensure_host_devices, use_compile_cache
 
 ensure_host_devices()
+use_compile_cache()
 
 from benchmarks import (ablations, analysis_bench, batch_lp, cache_bench,
                         concurrency_bench, dual_reducer_bench, grid,
@@ -48,6 +52,7 @@ def main() -> None:
     args = ap.parse_args()
     only = [s for s in args.only.split(",") if s]
     t0 = time.time()
+    failed = []
     print("name,us_per_call,derived")
     for name, mod in MODULES.items():
         if only and not any(o in name for o in only):
@@ -59,12 +64,16 @@ def main() -> None:
         # repro: allow[REPRO004] harness by design: record and continue
         except Exception as e:  # keep the harness going; record the failure
             print(f"{name},nan,ERROR:{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
         print(f"# {name} took {time.time() - t:.1f}s", flush=True)
     os.makedirs("results", exist_ok=True)
     with open("results/bench.csv", "w") as f:
         f.write("name,us_per_call,derived\n")
         f.write("\n".join(ROWS) + "\n")
     print(f"# total {time.time() - t0:.1f}s; {len(ROWS)} rows -> results/bench.csv")
+    if failed:
+        print(f"# FAILED: {','.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == '__main__':

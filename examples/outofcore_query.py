@@ -78,4 +78,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.hostdev import use_compile_cache
+    use_compile_cache()
     main()

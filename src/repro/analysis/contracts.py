@@ -37,7 +37,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.analysis.report import Violation
@@ -122,8 +122,9 @@ def collective_prims(jaxpr) -> List[Tuple[str, Tuple[str, ...]]]:
     found: List[Tuple[str, Tuple[str, ...]]] = []
 
     def visit(eqn, ctx):
-        # versioned primitive names (psum -> psum2) keep matching
-        if eqn.primitive.name.rstrip("0123456789") in _COLLECTIVE_PRIMS:
+        # shard_map(check_vma=True) traces psum as ``psum_invariant``
+        name = eqn.primitive.name.removesuffix("_invariant")
+        if name in _COLLECTIVE_PRIMS:
             found.append((eqn.primitive.name, ctx))
 
     walk_eqns(jaxpr, visit)
@@ -510,6 +511,7 @@ def check_kernel_pricing(m: int = 4, n: int = 4096) -> HotPathResult:
     may legitimately lower to host callbacks in HLO, so the contract here
     is dtype preservation plus no callback primitives OUTSIDE the
     pallas_call itself."""
+    from repro.kernels.ops import interpret_kernels
     from repro.kernels.pricing import pricing
     t0 = time.time()
     name = f"kernels.pricing@m{m}_n{n}"
@@ -521,7 +523,7 @@ def check_kernel_pricing(m: int = 4, n: int = 4096) -> HotPathResult:
                 jax.ShapeDtypeStruct((n,), jnp.int32),
                 f((n,)), f((n,)), f(()))
 
-    fn = lambda *a: pricing(*a)
+    fn = lambda *a: pricing(*a, interpret=interpret_kernels())
     jaxpr32 = _jaxpr_of(fn, *args(jnp.float32))
     jaxpr = _jaxpr_of(fn, *args(_F64))
     f64s = f64_introductions(jaxpr32)
@@ -544,11 +546,12 @@ def check_kernel_segstats(n: int = 4096, k: int = 4) -> HotPathResult:
     """The Pallas segment-stats kernel: f32 accumulation is BY DESIGN
     (preferred_element_type=f32) — the contract is that f32 inputs never
     promote to f64, and no callbacks escape the pallas_call."""
+    from repro.kernels.ops import interpret_kernels
     from repro.kernels.segstats import segstats_partials
     t0 = time.time()
     name = f"kernels.segstats@n{n}_k{k}"
     viol: List[Violation] = []
-    fn = lambda v, i: segstats_partials(v, i)
+    fn = lambda v, i: segstats_partials(v, i, interpret=interpret_kernels())
     a32 = (jax.ShapeDtypeStruct((n, k), jnp.float32),
            jax.ShapeDtypeStruct((n,), jnp.int32))
     jaxpr32 = _jaxpr_of(fn, *a32)

@@ -27,11 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# Version compat: jax >= 0.6 exposes jax.tree.flatten_with_path; 0.4.x only
-# has the tree_util spelling.
-_flatten_with_path = getattr(jax.tree, "flatten_with_path", None) or \
-    jax.tree_util.tree_flatten_with_path
-
 
 def _path_str(path) -> str:
     out = []
@@ -49,7 +44,7 @@ class CheckpointManager:
     # ------------------------------------------------------------- save
     def save(self, step: int, state: Any) -> str:
         leaves, treedef = jax.tree.flatten(state)
-        paths = [_path_str(p) for p, _ in _flatten_with_path(state)[0]]
+        paths = [_path_str(p) for p, _ in jax.tree.flatten_with_path(state)[0]]
         tmp = os.path.join(self.root, f".tmp_step_{step:06d}_{os.getpid()}")
         final = os.path.join(self.root, f"step_{step:06d}")
         os.makedirs(tmp, exist_ok=True)

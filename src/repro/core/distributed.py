@@ -40,7 +40,6 @@ benchmarks multi-pivot solves through this path against ``solve_lp_np``.
 """
 from __future__ import annotations
 
-import inspect
 import threading
 from collections import OrderedDict
 from typing import Dict, Tuple
@@ -50,22 +49,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:                                  # jax >= 0.6 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                   # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-compat wrapper: new jax spells the replication check
-    ``check_vma``; the 0.4.x experimental API calls it ``check_rep``."""
-    params = inspect.signature(_shard_map).parameters
-    kw = {"check_vma": check_vma} if "check_vma" in params else \
-        {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-
-
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels.pricing import pricing_math
@@ -85,6 +69,45 @@ def big_sentinel(dtype):
     default 32-bit floats, which silently breaks the masked reductions.
     """
     return jnp.asarray(jnp.finfo(jnp.dtype(dtype)).max, dtype)
+
+
+class ShardFailure(RuntimeError):
+    """A mesh shard or collective failed while ``solve_lp_dist`` ran:
+    the ``dist.shard`` fault site, or an XLA runtime error from a step
+    that had already compiled and run.  The one failure the solver
+    answers with its single-host fallback."""
+
+
+def _run_step(step, ran, *args):
+    """Call a mesh step.  Its first call compiles it, and an XLA error
+    there (a lowering the backend refuses, an executable that does not
+    fit) propagates as it is: that is no shard failure.  Once the step
+    has run, an XLA runtime error from it is one."""
+    if step not in ran:
+        out = step(*args)
+        ran.add(step)
+        return out
+    try:
+        return step(*args)
+    except jax.errors.JaxRuntimeError as e:
+        raise ShardFailure(str(e)) from e
+
+
+def _pull(*xs):
+    """One device->host transfer of step outputs; an XLA runtime error
+    that surfaces here (the steps ran asynchronously) is a shard
+    failure."""
+    try:
+        return jax.device_get(xs)
+    except jax.errors.JaxRuntimeError as e:
+        raise ShardFailure(str(e)) from e
+
+
+def _running_sum(x):
+    """Inclusive prefix sum.  ``jnp.cumsum`` lowers on TPU to a
+    reduce-window that XLA takes minutes to compile in f64; the
+    associative scan compiles in seconds."""
+    return jax.lax.associative_scan(jnp.add, x)
 
 
 def _mesh_axes(mesh) -> Tuple[str, ...]:
@@ -146,8 +169,14 @@ def make_pq_step(mesh: Mesh, m: int, n: int,
         big = big_sentinel(ratio.dtype)
 
         # ---- BFRT pass 1: bucket the breakpoint ratios (psum: O(NB)) ----
-        rmax = jax.lax.pmax(jnp.max(jnp.where(finite, ratio, -big)), axes)
-        rmin = jax.lax.pmin(jnp.min(jnp.where(finite, ratio, big)), axes)
+        # the global ratio range from one all_gather of (max, -min):
+        # XLA:TPU lowers only SUM all-reduces in f64, so pmax/pmin of an
+        # f64 scalar do not compile for the chip
+        ext = jax.lax.all_gather(
+            jnp.stack([jnp.max(jnp.where(finite, ratio, -big)),
+                       -jnp.min(jnp.where(finite, ratio, big))]),
+            axes).reshape(-1, 2)
+        rmax, rmin = jnp.max(ext[:, 0]), -jnp.max(ext[:, 1])
         span = jnp.maximum(rmax - rmin, 1e-12)
         # keep the edge grid in the pricing dtype: under x64 the bare
         # int-arange / int division promotes to f64 and silently drags
@@ -159,7 +188,7 @@ def make_pq_step(mesh: Mesh, m: int, n: int,
         hist_l = jnp.zeros(num_buckets, cost.dtype).at[bucket].add(
             jnp.where(finite, cost, 0.0))
         hist = jax.lax.psum(hist_l, axes)
-        csum = jnp.cumsum(hist)
+        csum = _running_sum(hist)
         crossed = csum >= budget - 1e-12
         bidx = jnp.argmax(crossed)
         has_cross = jnp.any(crossed)
@@ -198,7 +227,8 @@ def make_pq_step(mesh: Mesh, m: int, n: int,
         order = jnp.argsort(jnp.where(valid_g, r_g, big))
         r_s = r_g[order]
         valid_s = valid_g[order]
-        csum_in = base + jnp.cumsum(jnp.where(valid_s, cost_g[order], 0.0))
+        csum_in = base + _running_sum(jnp.where(valid_s, cost_g[order],
+                                                0.0))
         crossed_in = (csum_in >= budget - 1e-12) & valid_s
         pos = jnp.argmax(crossed_in)
         found = jnp.any(crossed_in)
@@ -489,14 +519,19 @@ def solve_lp_dist(c, A_t, bl, bu, ub, *, mesh: Mesh, lb=None,
     and one ``update_step`` (the O(n/p) d-axpy + bookkeeping, no
     collectives).
 
-    Resilience: a shard failure (any exception out of the mesh loop,
-    including the ``dist.shard`` fault-injection site) or a degenerate
-    stall past ``stall_bland`` (Bland mode is host-side only) falls back
-    to ``solve_lp_np`` on a single host, warm-started from the basis
-    snapshot at the point of failure, with the same budget — noted as
-    ``single_host_fallback`` in ``LPResult.notes``.
+    ``LPResult.pivot_stats`` counts exact and conservative BFRT pivots
+    and the ``shards`` (devices) that hold A.
+
+    Resilience: a :class:`ShardFailure` (the ``dist.shard`` fault site,
+    or an XLA runtime error from a step that has already compiled and
+    run) or a degenerate stall past ``stall_bland`` (Bland mode is
+    host-side only) falls back to ``solve_lp_np`` on a single host,
+    warm-started from the basis snapshot at the point of failure, with
+    the same budget — noted as ``single_host_fallback`` in
+    ``LPResult.notes``, which ``SolveReport.absorb_lp`` records as a
+    ladder rung.  An error while a step lowers or compiles propagates.
     """
-    from repro.core.guard import THETA_EPS, NumericalMonitor
+    from repro.core.guard import HOST_FALLBACK, THETA_EPS, NumericalMonitor
     from repro.core.lp import (BUDGET, INFEASIBLE, ITER_LIMIT, OPTIMAL,
                                LPResult, REFACTOR_EVERY, _prep,
                                solve_lp_np)
@@ -538,6 +573,7 @@ def solve_lp_dist(c, A_t, bl, bu, ub, *, mesh: Mesh, lb=None,
     l_dev = jax.device_put(pad(l), vec_sh)
     u_dev = jax.device_put(pad(u), vec_sh)
     state_dev = jax.device_put(state0, vec_sh)
+    shards = len(A_dev.sharding.device_set)     # devices pricing A
 
     pq_step, update_step, refresh_step = _cached_steps(
         mesh, m, Npad, num_buckets, gather_k)
@@ -559,12 +595,15 @@ def solve_lp_dist(c, A_t, bl, bu, ub, *, mesh: Mesh, lb=None,
         y = np.zeros(m)
         since = refactor_every      # force a factorization on entry
 
+    ran = set()                     # steps that have compiled and run
+
     def refresh():
         nonlocal Binv, xB, y, d_dev, since
         Binv = np.linalg.inv(A[:, basis])
         y = Binv.T @ cf[basis]
-        d_dev, axn = refresh_step(A_dev, cf_dev, state_dev, l_dev, u_dev,
-                                  _put(y, rep_sh))
+        d_dev, axn = _run_step(refresh_step, ran, A_dev, cf_dev, state_dev,
+                               l_dev, u_dev, _put(y, rep_sh))
+        (axn,) = _pull(axn)
         xB = -Binv @ np.asarray(axn)
         since = 0
 
@@ -602,11 +641,11 @@ def solve_lp_dist(c, A_t, bl, bu, ub, *, mesh: Mesh, lb=None,
                 delta = xB[r] - (uB[r] if above else lB[r])
                 s = 1.0 if delta > 0 else -1.0
 
-                faults.maybe_raise(faults.SHARD, RuntimeError)
+                faults.maybe_raise(faults.SHARD, ShardFailure)
                 rho = _put(Binv[r], rep_sh)
                 (alpha_dev, flip_dev, r_best, q, d_q, at_up_q, Acol, fvec,
-                 n_flips, has_cross, exact) = pq_step(
-                    A_dev, d_dev, l_dev, u_dev, state_dev, rho,
+                 n_flips, has_cross, exact) = _run_step(
+                    pq_step, ran, A_dev, d_dev, l_dev, u_dev, state_dev, rho,
                     _put(s, rep_sh), _put(abs(delta), rep_sh))
                 # ONE explicit device->host pull for everything the host
                 # loop consumes this pivot (alpha/flip stay sharded).
@@ -615,8 +654,7 @@ def solve_lp_dist(c, A_t, bl, bu, ub, *, mesh: Mesh, lb=None,
                 # strict_numerics test fixture (jax.transfer_guard)
                 # rejects them outright.
                 (q, d_q, at_up_q, Acol, fvec, has_cross, exact) = \
-                    jax.device_get((q, d_q, at_up_q, Acol, fvec,
-                                    has_cross, exact))
+                    _pull(q, d_q, at_up_q, Acol, fvec, has_cross, exact)
                 if not bool(has_cross):
                     if since > 0:   # could be drift: retry on fresh factors
                         refresh()
@@ -646,8 +684,8 @@ def solve_lp_dist(c, A_t, bl, bu, ub, *, mesh: Mesh, lb=None,
                 Binv = Binv - np.outer(w, Binv_r)
                 Binv[r] = Binv_r
                 basis[r] = q
-                d_dev, state_dev = update_step(
-                    d_dev, state_dev, alpha_dev, flip_dev,
+                d_dev, state_dev = _run_step(
+                    update_step, ran, d_dev, state_dev, alpha_dev, flip_dev,
                     _put(theta, rep_sh), _put(q, rep_sh, np.int64),
                     _put(leave, rep_sh, np.int64), _put(above, rep_sh))
                 since += 1
@@ -667,9 +705,9 @@ def solve_lp_dist(c, A_t, bl, bu, ub, *, mesh: Mesh, lb=None,
                         break
                 else:
                     stall = 0
-    # repro: allow[REPRO004] guard contract: any shard/collective failure
-    # (incl. the dist.shard fault site) falls back to the single-host twin
-    except Exception as e:          # dead shard / collective failure
+    # guard contract: only a shard failure falls back to the single-host
+    # twin; a bug or a refused compile propagates
+    except ShardFailure as e:       # dead shard / collective failure
         fallback_reason = f"{type(e).__name__}: {e}"
 
     if budget is not None:
@@ -678,14 +716,14 @@ def solve_lp_dist(c, A_t, bl, bu, ub, *, mesh: Mesh, lb=None,
     if fallback_reason is not None:
         # single-host fallback, warm-started from the failure-point basis
         state_np = np.asarray(state_dev)[:N]
-        notes.append(f"single_host_fallback: {fallback_reason}")
+        notes.append(f"{HOST_FALLBACK}: {fallback_reason}")
         res = solve_lp_np(c, A_t, bl, bu, ub, lb=lb, max_iters=max_iters,
                           tol=tol, warm_start=(basis.copy(),
                                                state_np == 1),
                           budget=budget, monitor=monitor)
         res.notes = tuple(notes) + res.notes
         res.pivot_stats = {"exact": n_exact, "conservative": n_cons,
-                           "fallback": 1}
+                           "shards": shards, "fallback": 1}
         return res
 
     # final answer always from a fresh factorization (twin parity)
@@ -704,7 +742,8 @@ def solve_lp_dist(c, A_t, bl, bu, ub, *, mesh: Mesh, lb=None,
     obj_min = float(cf @ np.where(np.isfinite(x), x, 0.0))
     res = LPResult(status, x[:n], obj_min, iters, basis.copy(),
                    at_upper.copy(), y * scale, notes=tuple(notes))
-    res.pivot_stats = {"exact": n_exact, "conservative": n_cons}
+    res.pivot_stats = {"exact": n_exact, "conservative": n_cons,
+                       "shards": shards}
     return res
 
 

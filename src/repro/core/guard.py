@@ -68,6 +68,9 @@ STALL_REFACTOR = 12       # degenerate-pivot streak -> force refactorize
 STALL_BLAND = 24          # streak -> escalate to Bland's-rule pivoting
 THETA_EPS = 1e-12         # |theta| below this = degenerate (no progress)
 
+# LP note (and ladder rung) of a mesh LP re-solved on the single host
+HOST_FALLBACK = "single_host_fallback"
+
 
 # --------------------------------------------------------------- budget
 
@@ -231,11 +234,15 @@ class SolveReport:
         self.note(f"fallback:{name}" + (f" ({detail})" if detail else ""))
 
     def absorb_lp(self, res) -> None:
-        """Account one LPResult (any twin) into the report."""
+        """Account one LPResult (any twin) into the report.  A mesh LP
+        that fell back to the single host is a rung: the same LP, solved
+        exactly, so it does not degrade."""
         self.lp_calls += 1
         self.lp_pivots += int(getattr(res, "iters", 0))
         for n in getattr(res, "notes", ()) or ():
             self.note(n)
+            if n.startswith(HOST_FALLBACK):
+                self.rung(HOST_FALLBACK)
         # status codes: 0 OPTIMAL, 1 ITER_LIMIT, 2 INFEASIBLE, 3 BUDGET
         if getattr(res, "status", 0) in (1, 3):
             self.lp_truncated += 1

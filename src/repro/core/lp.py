@@ -468,11 +468,39 @@ import jax.numpy as jnp
 from functools import partial
 
 
+def basis_inverse(B):
+    """Inverse of the m x m basis by Gauss–Jordan with partial pivoting.
+
+    ``jnp.linalg.inv`` lowers to an LU decomposition that XLA:TPU
+    implements for f32 and c64 only; this loop is plain elementwise
+    jnp, so it compiles in f64 on every backend.  m is tiny (3–20), so
+    the O(m^3) sweep costs nothing next to one pricing pass.  Row swaps
+    and pivot-row reads are one-hot selects, not gathers, so the
+    batched engine can vmap it.  A singular basis yields non-finite
+    entries, as ``jnp.linalg.inv`` does.
+    """
+    m = B.shape[0]
+    rows = jnp.arange(m)
+    M = jnp.concatenate([B, jnp.eye(m, dtype=B.dtype)], axis=1)
+
+    def eliminate(k, M):
+        col = M[:, k]
+        p = jnp.argmax(jnp.where(rows >= k, jnp.abs(col), -1.0))
+        at_k, at_p = (rows == k)[:, None], (rows == p)[:, None]
+        row_k = jnp.sum(jnp.where(at_k, M, 0.0), axis=0)
+        row_p = jnp.sum(jnp.where(at_p, M, 0.0), axis=0)
+        M = jnp.where(at_k, row_p, jnp.where(at_p, row_k, M))
+        piv = row_p / row_p[k]
+        return jnp.where(at_k, piv, M - jnp.outer(M[:, k], piv))
+
+    return jax.lax.fori_loop(0, m, eliminate, M)[:, m:]
+
+
 def _refreshed(cf, A, l, u, basis, in_basis, at_upper):
     """Full refactorization of the revised-simplex factor state.  Shared
     by the single-instance jitted twin and the batched bound-variant
     engine (``repro.core.lp_batch``), which vmaps it over instances."""
-    Binv = jnp.linalg.inv(A[:, basis])
+    Binv = basis_inverse(A[:, basis])
     # NOTE: masked selects, not ``.at[basis].set`` scatters — a vmapped
     # scatter lowers to a K*m-trip sequential loop on CPU; ``in_basis``
     # is the exact membership mask of ``basis`` by invariant
@@ -568,6 +596,37 @@ def _pivot_iter(cf, A, l, u, tol, refactor_every, state):
     return _pivot_core(cf, A, l, u, tol, refactor_every, state)
 
 
+def _bfrt_walk(ratio, flip_cost, elig, budget, iN):
+    """BFRT crossing without a sort: ``(q, crossed)``, where q is the
+    first eligible breakpoint in (ratio, index) order at which the
+    running flip cost reaches ``budget``.
+
+    Each trip takes the next breakpoint by one masked argmin and adds
+    its cost, so the running sum is ``np.cumsum``'s left-to-right sum
+    over the stable argsort — the host twin's crossing, bit for bit.
+    Trips = flips + 1.  A length-N sort or ``jnp.cumsum`` in f64 takes
+    XLA:TPU minutes to compile; this loop compiles in seconds.
+    """
+    target = budget - 1e-12
+
+    def cond(c):
+        return ~c[0]
+
+    def body(c):
+        _, run, r_prev, i_prev, _, _ = c
+        after = elig & ((ratio > r_prev) | ((ratio == r_prev) & (iN > i_prev)))
+        j = jnp.argmin(jnp.where(after, ratio, jnp.inf))
+        run = run + flip_cost[j]
+        hit = after[j] & (run >= target)
+        return (hit | ~after[j], run, ratio[j], iN[j], j, hit)
+
+    zero = jnp.zeros((), ratio.dtype)
+    init = (~jnp.any(elig), zero, -jnp.inf + zero, iN[0] - 1, iN[0],
+            jnp.bool_(False))
+    _, _, _, _, q, crossed = jax.lax.while_loop(cond, body, init)
+    return q, crossed
+
+
 def _pivot_core(cf, A, l, u, tol, refactor_every, state, active=None):
     """The pivot proper: BFRT column selection + Sherman–Morrison
     update, on factors the caller has already refreshed as needed.
@@ -607,22 +666,17 @@ def _pivot_core(cf, A, l, u, tol, refactor_every, state, active=None):
     width = u - l
     flip_cost = jnp.where(elig, jnp.abs(alpha) * width, 0.0)
 
-    order = jnp.argsort(ratio)
-    csum_all = jnp.cumsum(flip_cost[order])
-    flip_budget = jnp.abs(delta)
-    elig_sorted = elig[order]
-    crossed = (csum_all >= flip_budget - 1e-12) & elig_sorted
-    cross_pos = jnp.argmax(crossed)          # first True (0 if none)
+    iN = jnp.arange(N)
+    q_walk, crossed = _bfrt_walk(ratio, flip_cost, elig & ~bland,
+                                 jnp.abs(delta), iN)
     # Bland mode: smallest-index min-ratio column, no bound flips
     rmin = jnp.min(ratio)
     q_bland = jnp.argmax(elig & (ratio <= rmin + 1e-12))
-    has_cross = jnp.any(crossed) | (bland & any_elig)
-    q = jnp.where(bland, q_bland, order[cross_pos])
-    # only flip breakpoints strictly before the crossing in sorted
-    # order; argsort is stable, so "sorted before q" is exactly the
-    # lexicographic compare on (ratio, index) — no inverse-permutation
-    # scatter (which lowers to a K*N-trip sequential loop when vmapped)
-    iN = jnp.arange(N)
+    has_cross = jnp.where(bland, any_elig, crossed)
+    q = jnp.where(bland, q_bland, q_walk)
+    # only flip breakpoints strictly before the crossing in the walk's
+    # (ratio, index) order — no inverse-permutation scatter (which
+    # lowers to a K*N-trip sequential loop when vmapped)
     flip_mask = (elig & ~bland
                  & ((ratio < ratio[q])
                     | ((ratio == ratio[q]) & (iN < q))))

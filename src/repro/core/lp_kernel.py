@@ -10,9 +10,14 @@ the TPU kernels:
     is a single fused pass over A (one rank-1 matvec, one HBM read);
   * BFRT breakpoint selection -> kernels.bfrt (bucketed two-pass select).
 
-On CPU the kernels execute in interpret mode (slow, correctness only);
-on TPU they are the production path.  Tested against solve_lp_np on
-random LPs in tests/test_lp_kernel.py and tests/test_warm_start.py.
+The pricing operands are f32 on every backend, because Mosaic has no
+f64: the kernel's alpha, ratios and flip costs steer the pivot choice,
+while the factor state (Binv, xB, y, d) stays in the input dtype and the
+answer is rebuilt from a fresh f64 factorization, so
+``verify_optimality`` certifies it.  ``repro.kernels.ops`` decides
+whether the kernels are compiled (TPU) or interpreted (elsewhere: slow,
+correctness only).  Tested against solve_lp_np on random LPs in
+tests/test_lp_kernel.py and tests/test_warm_start.py.
 """
 from __future__ import annotations
 
@@ -26,8 +31,9 @@ import numpy as np
 from repro.core.guard import (DRIFT_TOL, NumericalMonitor, STALL_REFACTOR,
                               SolveBudget, THETA_EPS)
 from repro.core.lp import (BUDGET, INFEASIBLE, ITER_LIMIT, OPTIMAL,
-                           LPResult, REFACTOR_EVERY, _prep)
+                           LPResult, REFACTOR_EVERY, _prep, basis_inverse)
 from repro.kernels.bfrt import bfrt_select
+from repro.kernels.ops import interpret_kernels
 from repro.kernels.pricing import pricing
 
 
@@ -41,11 +47,13 @@ def _solve_lp_kernel_jax(cf, A, l, u, basis0, at_upper0, max_iters: int,
     n = N - m
     tol = 1e-7
 
+    A32 = A.astype(jnp.float32)          # resident f32 copy for pricing
+    zeros32 = jnp.zeros(N, jnp.float32)
     in_basis0 = jnp.zeros(N, bool).at[basis0].set(True)
     at_upper0 = at_upper0 & ~in_basis0
 
     def refreshed(basis, in_basis, at_upper):
-        Binv = jnp.linalg.inv(A[:, basis])
+        Binv = basis_inverse(A[:, basis])
         xN = jnp.where(in_basis, 0.0, jnp.where(at_upper, u, l))
         xN = xN.at[basis].set(0.0)
         xB = -Binv @ (A @ xN)
@@ -99,12 +107,13 @@ def _solve_lp_kernel_jax(cf, A, l, u, basis0, at_upper0, max_iters: int,
         # ---- Pallas: fused pricing, the single O(mn) sweep over A ----
         state_code = jnp.where(in_basis, 2,
                                jnp.where(at_upper, 1, 0)).astype(jnp.int32)
-        lo_safe = jnp.where(jnp.isfinite(l), l, 0.0)
         width = jnp.where(jnp.isfinite(u - l), u - l, 1e30)
-        alpha, ratio, cost = pricing(A, rho, d, state_code,
-                                     lo_safe, lo_safe + width, s,
-                                     block=min(2048, N),
-                                     interpret=interpret)
+        f32 = jnp.float32
+        alpha, ratio, cost = pricing(A32, rho.astype(f32), d.astype(f32),
+                                     state_code, zeros32, width.astype(f32),
+                                     s.astype(f32), block=min(2048, N),
+                                     tol=tol, interpret=interpret)
+        alpha = alpha.astype(A.dtype)
         # ---- Pallas: bucketed BFRT select ----
         q, flip_mask, has_cross = bfrt_select(ratio, cost, jnp.abs(delta),
                                               interpret=interpret)
@@ -179,14 +188,11 @@ def _solve_lp_kernel_jax(cf, A, l, u, basis0, at_upper0, max_iters: int,
 
 def solve_lp_kernel(c, A_t, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
                     max_iters: int = 5000,
-                    interpret: Optional[bool] = None,
                     warm_start=None,
                     budget: Optional[SolveBudget] = None,
                     monitor: Optional[NumericalMonitor] = None) -> LPResult:
     """Kernel-backed twin of core.lp.solve_lp (same conventions, including
     the warm-start and budget/monitor contracts)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     arrs, scale, m, n, start = _prep(c, A_t, bl, bu, ub, lb, warm_start)
     if arrs is None:
         return LPResult(INFEASIBLE, np.zeros(n), 0.0, 0,
@@ -207,7 +213,8 @@ def solve_lp_kernel(c, A_t, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
         cap = budget.lp_iter_cap(max_iters)
     status, x, obj, it, basis, at_upper, y, n_drift = _solve_lp_kernel_jax(
         jnp.asarray(cf), jnp.asarray(A), jnp.asarray(l), jnp.asarray(u),
-        jnp.asarray(basis0), jnp.asarray(at_upper0), cap, interpret)
+        jnp.asarray(basis0), jnp.asarray(at_upper0), cap,
+        interpret_kernels())
     status, it, n_drift = int(status), int(it), int(n_drift)
     if n_drift:
         notes.append(f"drift: {n_drift} forced refactorizations")

@@ -25,12 +25,26 @@ require a host-side sorted copy of the relation.
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+# Chunks the mesh group-stats pass has dispatched, and the most devices
+# one of them was sharded over (mutated under _MESH_STATS_LOCK)
+SHARED_MUTABLE = ("_MESH_STATS",)
+_MESH_STATS = {"chunks": 0, "devices": 0}
+_MESH_STATS_LOCK = threading.Lock()
+
+
+def mesh_stats_counts() -> dict:
+    """Dispatch counters of the mesh group-stats pass (atomic snapshot):
+    ``chunks`` run sharded, and the most ``devices`` one spanned."""
+    with _MESH_STATS_LOCK:
+        return dict(_MESH_STATS)
 
 
 # ---------------------------------------------------------------- split tree
@@ -202,6 +216,8 @@ _BACKENDS: Dict[str, Callable[..., Partition]] = {}
 
 def register_backend(name: str):
     def deco(fn):
+        # repro: allow[REPRO010] backends register while their module
+        # is imported, which the interpreter's import lock serializes
         _BACKENDS[name] = fn
         return fn
     return deco
@@ -305,9 +321,14 @@ def group_stats(X: np.ndarray, order: np.ndarray, offsets: np.ndarray, *,
             rows = ((chunk_rows + nd - 1) // nd) * nd
             cpad = np.pad(chunk, ((0, rows - len(chunk)), (0, 0)))
             ipad = np.pad(ids, (0, rows - len(ids)), constant_values=G)
-            cnt_d, sum_d, _ = fn(jax.device_put(jnp.asarray(cpad), vsh),
+            v_dev = jax.device_put(jnp.asarray(cpad), vsh)
+            cnt_d, sum_d, _ = fn(v_dev,
                                  jax.device_put(jnp.asarray(ipad), ish))
             sums += np.asarray(sum_d)[:G]
+            with _MESH_STATS_LOCK:
+                _MESH_STATS["chunks"] += 1
+                _MESH_STATS["devices"] = max(_MESH_STATS["devices"],
+                                             len(v_dev.sharding.device_set))
         else:
             loc = ids - u0
             nloc = u1 - u0 + 1

@@ -21,11 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Version compat (same pattern as the shard_map shim in core.distributed):
-# jax >= 0.7 spells it pltpu.CompilerParams; 0.4.x calls it TPUCompilerParams.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 NEG_INF = -1e30
 
 
@@ -74,9 +69,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "block_q", "block_k", "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+def flash_attention(q, k, v, *, interpret: bool, causal: bool = True,
+                    window: int = 0, block_q: int = 128, block_k: int = 128):
     """q/k/v: (BH, S, d).  Returns (BH, S, d)."""
     BH, S, d = q.shape
     block_q = min(block_q, S)
@@ -103,6 +97,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(q, k, v)

@@ -1,14 +1,17 @@
-"""Jit'd public wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels, and the one place that decides
+whether a kernel is compiled or interpreted.
 
-``interpret`` defaults to True on CPU (this container) so the kernel bodies
-execute in Python for correctness; on a real TPU backend pass
-``interpret=False`` (the wrappers pick this automatically from the default
-device platform).
+The kernel signatures take ``interpret`` without a default.  These
+wrappers pass ``interpret_kernels()``: compiled by Mosaic on a TPU,
+interpreted (kernel bodies executed as jnp on the host) on any other
+backend, which is how the CPU test suite checks them against their
+oracles in ``ref.py``.  On a TPU nothing interprets.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.attention import flash_attention
 from repro.kernels.bfrt import bfrt_histogram, bfrt_select
@@ -21,23 +24,24 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def auto_interpret() -> bool:
+def interpret_kernels() -> bool:
+    """Interpret the Pallas kernels everywhere except on a TPU."""
     return not on_tpu()
 
 
 def pricing_op(A, rho, d, state, lo, hi, s, **kw):
-    kw.setdefault("interpret", auto_interpret())
-    return pricing(A, rho, d, state, lo, hi, s, **kw)
+    return pricing(A, rho, d, state, lo, hi, s,
+                   interpret=interpret_kernels(), **kw)
 
 
 def bfrt_select_op(ratio, cost, budget, **kw):
-    kw.setdefault("interpret", auto_interpret())
-    return bfrt_select(ratio, cost, budget, **kw)
+    return bfrt_select(ratio, cost, budget, interpret=interpret_kernels(),
+                       **kw)
 
 
 def segment_stats_op(vals, ids, num_groups, **kw):
-    kw.setdefault("interpret", auto_interpret())
-    return segment_stats(vals, ids, num_groups, **kw)
+    return segment_stats(vals, ids, num_groups,
+                         interpret=interpret_kernels(), **kw)
 
 
 def segment_stats_auto(vals, ids, num_groups):
@@ -50,10 +54,7 @@ def segment_stats_auto(vals, ids, num_groups):
     from the global mean relative to their spread lose variance precision;
     see ROADMAP "TPU-resident build" for the per-block centering follow-on.
     """
-    import numpy as np
-
     if on_tpu():
-        import jax.numpy as jnp
         cnt, sm, sq = segment_stats(jnp.asarray(vals, jnp.float32),
                                     jnp.asarray(ids, jnp.int32),
                                     num_groups, interpret=False)
@@ -64,7 +65,6 @@ def segment_stats_auto(vals, ids, num_groups):
 
 def flash_attention_op(q, k, v, *, num_kv_heads=None, **kw):
     """q: (B, S, H, d); k/v: (B, S, KV, d).  GQA expansion then kernel."""
-    kw.setdefault("interpret", auto_interpret())
     B, S, H, d = q.shape
     KV = k.shape[2]
     if KV != H:
@@ -74,11 +74,11 @@ def flash_attention_op(q, k, v, *, num_kv_heads=None, **kw):
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, d)
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, S, d)
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, S, d)
-    o = flash_attention(qf, kf, vf, **kw)
+    o = flash_attention(qf, kf, vf, interpret=interpret_kernels(), **kw)
     return o.reshape(B, H, S, d).transpose(0, 2, 1, 3)
 
 
 __all__ = ["pricing_op", "bfrt_select_op", "segment_stats_op",
            "segment_stats_auto", "segment_stats_np", "flash_attention_op",
            "bfrt_histogram", "segstats_partials", "on_tpu",
-           "auto_interpret"]
+           "interpret_kernels"]

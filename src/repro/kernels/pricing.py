@@ -9,11 +9,11 @@ Per iteration the revised dual simplex needs, for every column j of A
 The reduced costs d are MAINTAINED by the revised simplex (one O(n) axpy
 ``d -= theta * alpha`` per pivot — see ``repro.core.lp``), so unlike the
 textbook loop there is no second matvec ``c - y @ A`` here: this kernel
-performs the single O(mn) sweep of A per simplex iteration — one rank-1
-MXU matvec + VPU elementwise, one HBM read of A total.  This is ~45% of
+performs the single O(mn) sweep of A per simplex iteration — an m-row
+sublane reduction + VPU elementwise, one HBM read of A total.  This is ~45% of
 dual-simplex time in the paper (OpenMP over n).
 
-Block layout: A tile (m, B) in VMEM; rho broadcast as a (1, m) operand;
+Block layout: A tile (m, B) in VMEM; rho as a resident (m, 1) column;
 d/state/lo/hi as (1, B) tiles; out tiles (1, B).  n is padded to a
 multiple of BLOCK.
 """
@@ -23,6 +23,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK = 2048
@@ -52,15 +53,15 @@ def _pricing_kernel(A_ref, rho_ref, d_ref, state_ref,
                     lo_ref, hi_ref, s_ref,
                     alpha_ref, ratio_ref, cost_ref, *, tol: float):
     A = A_ref[...]                       # (m, B)
-    rho = rho_ref[...]                   # (1, m)
+    rho = rho_ref[...]                   # (m, 1)
     d = d_ref[...]                       # (1, B) maintained reduced costs
     state = state_ref[...]               # (1, B) 0=at_lo, 1=at_up, 2=basic
     lo = lo_ref[...]
     hi = hi_ref[...]
-    s = s_ref[0, 0]                      # +-1, scalar
+    s = s_ref[...]                       # (1, 1): +-1
 
-    acc_t = A.dtype  # f32 accumulation on MXU for <=f32; f64 stays f64
-    alpha = jnp.dot(rho, A, preferred_element_type=acc_t)         # (1, B)
+    # m is tiny: a sublane reduction on the VPU, exact in the input dtype
+    alpha = jnp.sum(rho * A, axis=0, keepdims=True)               # (1, B)
     ratio, cost = pricing_math(alpha, d, state, hi - lo, s, tol)
 
     alpha_ref[...] = alpha
@@ -69,16 +70,21 @@ def _pricing_kernel(A_ref, rho_ref, d_ref, state_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret", "tol"))
-def pricing(A, rho, d, state, lo, hi, s, *, block: int = DEFAULT_BLOCK,
-            interpret: bool = True, tol: float = 1e-9):
-    """Fused pricing over columns.  A: (m, n) f32/f64 -> (alpha, ratio, cost).
+def pricing(A, rho, d, state, lo, hi, s, *, interpret: bool,
+            block: int = DEFAULT_BLOCK, tol: float = 1e-9):
+    """Fused pricing over columns.  A: (m, n) -> (alpha, ratio, cost).
 
     d: (n,) maintained reduced costs.  state: int32 (n,) with
     0 = nonbasic-at-lower, 1 = nonbasic-at-upper, 2 = basic.
-    s: scalar sign of the primal infeasibility delta.
+    s: scalar sign of the primal infeasibility delta.  Compiled for the
+    TPU the operands must be f32 (Mosaic has no f64); interpreted, any
+    float dtype runs.
     """
     m, n = A.shape
     dt = A.dtype
+    if not interpret and jnp.dtype(dt).itemsize > 4:
+        raise TypeError("pricing: the TPU kernel takes f32 operands "
+                        "(Mosaic has no float64)")
     block = min(block, n)
     pad = (-n) % block
     if pad:
@@ -89,28 +95,23 @@ def pricing(A, rho, d, state, lo, hi, s, *, block: int = DEFAULT_BLOCK,
         hi = jnp.pad(hi, (0, pad))
     npad = A.shape[1]
     grid = (npad // block,)
+    zero = np.int32(0)                   # index maps stay i32 under x64
+    row = pl.BlockSpec((1, block), lambda i: (zero, i))
 
     kernel = functools.partial(_pricing_kernel, tol=tol)
     alpha, ratio, cost = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((m, block), lambda i: (0, i)),
-            pl.BlockSpec((1, m), lambda i: (0, 0)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((m, block), lambda i: (zero, i)),
+            pl.BlockSpec((m, 1), lambda i: (zero, zero)),
+            row, row, row, row,
+            pl.BlockSpec((1, 1), lambda i: (zero, zero)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-            pl.BlockSpec((1, block), lambda i: (0, i)),
-        ],
+        out_specs=[row, row, row],
         out_shape=[jax.ShapeDtypeStruct((1, npad), dt)] * 3,
         interpret=interpret,
-    )(A, rho.reshape(1, m), d.reshape(1, npad),
-      state.reshape(1, npad).astype(dt), lo.reshape(1, npad),
-      hi.reshape(1, npad), jnp.asarray(s, dt).reshape(1, 1))
+    )(A, rho.reshape(m, 1).astype(dt), d.reshape(1, npad).astype(dt),
+      state.reshape(1, npad).astype(dt), lo.reshape(1, npad).astype(dt),
+      hi.reshape(1, npad).astype(dt), jnp.asarray(s, dt).reshape(1, 1))
     return alpha[0, :n], ratio[0, :n], cost[0, :n]

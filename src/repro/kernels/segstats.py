@@ -17,70 +17,76 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK = 512
 
 
-def _segstats_kernel(vals_ref, ids_ref, base_ref,
-                     cnt_ref, sum_ref, sq_ref):
-    vals = vals_ref[...]                 # (B, k)
-    ids = ids_ref[...]                   # (1, B) int32
-    base = base_ref[...]                 # (1, 1) int32: first id in block
-    B = vals.shape[0]
-    rel = ids[0] - base[0, 0]            # (B,) in [0, B)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (B, B), 1)
-    onehot = (rel[:, None] == cols).astype(vals.dtype)      # (B, B)
-    valid = (rel >= 0) & (rel < B)
-    onehot = onehot * valid[:, None].astype(vals.dtype)
-    cnt_ref[...] = jnp.sum(onehot, axis=0, keepdims=True)   # (1, B)
-    sum_ref[...] = jnp.dot(onehot.T, vals,
-                           preferred_element_type=jnp.float32)  # (B, k)
-    sq_ref[...] = jnp.dot(onehot.T, vals * vals,
-                          preferred_element_type=jnp.float32)
+def _segstats_kernel(vals_ref, ids_ref, cnt_ref, sum_ref, sq_ref):
+    vals = vals_ref[...]                 # (k, B): attributes on sublanes
+    ids = ids_ref[...]                   # (1, B) int32, sorted ascending
+    B = ids.shape[1]
+    rel = ids - jnp.min(ids)             # block-local ids; min = first id
+    rows = jax.lax.broadcasted_iota(jnp.int32, (B, B), 0)
+    # onehot[g, t] = tuple t is in local group g; rel >= B never matches
+    onehot = (rows == rel).astype(jnp.float32)               # (B, B)
+    nt = (((1,), (1,)), ((), ()))        # contract the tuple (lane) axes
+    hi = jax.lax.Precision.HIGHEST       # no bf16 passes on the MXU
+    cnt_ref[...] = jax.lax.dot_general(
+        jnp.ones_like(ids, jnp.float32), onehot, nt,
+        preferred_element_type=jnp.float32)                  # (1, B)
+    sum_ref[...] = jax.lax.dot_general(
+        vals, onehot, nt, precision=hi,
+        preferred_element_type=jnp.float32)                  # (k, B)
+    sq_ref[...] = jax.lax.dot_general(
+        vals * vals, onehot, nt, precision=hi,
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def segstats_partials(vals, ids, *, block: int = DEFAULT_BLOCK,
-                      interpret: bool = True):
+def segstats_partials(vals, ids, *, interpret: bool,
+                      block: int = DEFAULT_BLOCK):
     """Per-block partial (count, sum, sumsq) keyed by block-local group ids.
 
-    vals: (n, k); ids: (n,) int32 sorted ascending.
+    vals: (n, k); ids: (n,) int32 sorted ascending.  The kernel reads
+    ``vals`` transposed, (k, n), so the long tuple axis lies on lanes.
     Returns (bases (nb,), counts (nb, B), sums (nb, B, k), sqs (nb, B, k)).
     """
     n, k = vals.shape
     block = min(block, n)
     pad = (-n) % block
+    vals = vals.astype(jnp.float32)
     if pad:
         vals = jnp.pad(vals, ((0, pad), (0, 0)))
         # pad ids far beyond any real group so rel-id masking rejects them
         ids = jnp.pad(ids, (0, pad), constant_values=1 << 30)
     npad = vals.shape[0]
     nb = npad // block
-    bases = ids.reshape(nb, block)[:, 0:1]
+    bases = ids.reshape(nb, block)[:, 0]
+    zero = np.int32(0)                   # index maps stay i32 under x64
 
     cnt, sm, sq = pl.pallas_call(
         _segstats_kernel,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((block, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((k, block), lambda i: (zero, i)),
+            pl.BlockSpec((1, block), lambda i: (zero, i)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((block, k), lambda i: (i, 0)),
-            pl.BlockSpec((block, k), lambda i: (i, 0)),
+            pl.BlockSpec((1, block), lambda i: (zero, i)),
+            pl.BlockSpec((k, block), lambda i: (zero, i)),
+            pl.BlockSpec((k, block), lambda i: (zero, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, block), jnp.float32),
-            jax.ShapeDtypeStruct((nb * block, k), jnp.float32),
-            jax.ShapeDtypeStruct((nb * block, k), jnp.float32),
+            jax.ShapeDtypeStruct((1, npad), jnp.float32),
+            jax.ShapeDtypeStruct((k, npad), jnp.float32),
+            jax.ShapeDtypeStruct((k, npad), jnp.float32),
         ],
         interpret=interpret,
-    )(vals, ids.reshape(nb, block).reshape(nb, block), bases)
-    return (bases[:, 0], cnt, sm.reshape(nb, block, k),
-            sq.reshape(nb, block, k))
+    )(vals.T, ids.reshape(1, npad))
+    return (bases, cnt.reshape(nb, block), sm.T.reshape(nb, block, k),
+            sq.T.reshape(nb, block, k))
 
 
 def segment_stats_np(vals, ids, num_groups: int):
@@ -90,8 +96,6 @@ def segment_stats_np(vals, ids, num_groups: int):
     without a TPU, where interpreting the Pallas kernel would serialize
     the hot loop.  Same contract as :func:`segment_stats`.
     """
-    import numpy as np
-
     vals = np.asarray(vals, np.float64)
     ids = np.asarray(ids)
     n, k = vals.shape
@@ -120,8 +124,8 @@ def segment_stats_np(vals, ids, num_groups: int):
     return cnt, sums, sqs
 
 
-def segment_stats(vals, ids, num_groups: int, *, block: int = DEFAULT_BLOCK,
-                  interpret: bool = True):
+def segment_stats(vals, ids, num_groups: int, *, interpret: bool,
+                  block: int = DEFAULT_BLOCK):
     """Full segment stats: (counts (G,), sums (G, k), sumsqs (G, k))."""
     vals = jnp.asarray(vals)
     ids = jnp.asarray(ids, jnp.int32)

@@ -21,19 +21,9 @@ def make_local_mesh(data: int = 1, model: int = 1):
 
 
 def make_abstract_mesh(shape, axes):
-    """Version-compat AbstractMesh: build shardings without real devices.
-
-    Newer jax spells it ``AbstractMesh(axis_sizes, axis_names)``; 0.4.x
-    takes a single tuple of ``(name, size)`` pairs (same pattern as the
-    shard_map shim in ``repro.core.distributed``).
-    """
-    import inspect
-
+    """AbstractMesh: build shardings without real devices."""
     from jax.sharding import AbstractMesh
-    params = inspect.signature(AbstractMesh.__init__).parameters
-    if "axis_names" in params:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 # TPU v5e hardware constants used by the roofline analysis.

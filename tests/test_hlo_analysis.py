@@ -209,7 +209,7 @@ def test_real_lowering_roundtrip():
         c, _ = jax.lax.scan(body, x, None, length=L)
         return c
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     sm = shard_map(fn, mesh=mesh, in_specs=P("data"), out_specs=P("data"))
     hlo = jax.jit(sm).lower(
         jax.ShapeDtypeStruct((8,), jnp.float64)).compile().as_text()

@@ -9,7 +9,7 @@ from repro.core import partitioner
 from repro.core.bucketing import ArraySource
 from repro.core.dlv import dlv, dlv_heap, dlv_rounds, ratio_score
 from repro.core.hierarchy import Hierarchy, _min_gap
-from repro.core.partitioner import fit, group_stats
+from repro.core.partitioner import fit, group_stats, mesh_stats_counts
 
 BACKENDS = ["dlv", "kdtree", "bucketing"]
 
@@ -131,10 +131,14 @@ def test_group_stats_sharded_on_mesh(X):
     mesh = make_local_mesh(data=2, model=1)
     part = fit(X, backend="dlv", d_f=60)
     dense = group_stats(X, part.order, part.offsets)
+    before = mesh_stats_counts()["chunks"]
     sharded = group_stats(X, part.order, part.offsets, mesh=mesh,
                           chunk_rows=2048)
     for d, s in zip(dense, sharded):
         np.testing.assert_allclose(s, d, rtol=1e-8, atol=1e-8)
+    after = mesh_stats_counts()
+    assert after["chunks"] - before == -(-len(X) // 2048)
+    assert after["devices"] >= 2
 
 
 def test_hierarchy_chunked_build_matches_in_memory(X):

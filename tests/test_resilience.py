@@ -182,6 +182,58 @@ def test_dist_shard_fault_falls_back_to_single_host():
     assert res.pivot_stats.get("fallback") == 1
     assert res.status == ref.status == OPTIMAL
     assert abs(res.obj - ref.obj) <= 1e-6 * (1 + abs(ref.obj))
+    # the report a caller of engine.solve reads shows the fallback
+    report = guard.SolveReport()
+    report.absorb_lp(res)
+    assert report.fallbacks == [guard.HOST_FALLBACK]
+    assert report.finalize(True).status == guard.OK
+
+
+def _failing_update_step(monkeypatch, fail_on_call):
+    """Make the mesh update step raise an XLA runtime error on its
+    ``fail_on_call``-th call (1 = the call that compiles it)."""
+    import jax
+    from repro.core import distributed
+    real = distributed._cached_steps
+
+    def steps(*a, **k):
+        pq, update, refresh = real(*a, **k)
+        calls = []
+
+        def failing(*args):
+            calls.append(1)
+            if len(calls) == fail_on_call:
+                raise jax.errors.JaxRuntimeError("INTERNAL: shard lost")
+            return update(*args)
+        return pq, failing, refresh
+
+    monkeypatch.setattr(distributed, "_cached_steps", steps)
+
+
+def test_dist_runtime_error_after_first_run_falls_back(monkeypatch):
+    jax = pytest.importorskip("jax")
+    from repro.core.distributed import solve_lp_dist
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    c, A, bl, bu, ub = _random_lp(7)
+    ref = solve_lp_np(c, A, bl, bu, ub)
+    _failing_update_step(monkeypatch, fail_on_call=2)
+    res = solve_lp_dist(c, A, bl, bu, ub, mesh=mesh)
+    assert any("single_host_fallback: ShardFailure" in note
+               for note in res.notes)
+    assert res.status == ref.status == OPTIMAL
+    assert abs(res.obj - ref.obj) <= 1e-6 * (1 + abs(ref.obj))
+
+
+def test_dist_error_while_compiling_propagates(monkeypatch):
+    """An XLA error from a step's first (compiling) call is no shard
+    failure: it reaches the caller instead of a silent host re-solve."""
+    jax = pytest.importorskip("jax")
+    from repro.core.distributed import solve_lp_dist
+    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    c, A, bl, bu, ub = _random_lp(7)
+    _failing_update_step(monkeypatch, fail_on_call=1)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="shard lost"):
+        solve_lp_dist(c, A, bl, bu, ub, mesh=mesh)
 
 
 # ------------------------------------------------------ degradation ladder
