@@ -2,8 +2,9 @@
 batched-frontier DLV build (``dlv_rounds``) vs the seed heap build
 (``dlv_heap``) vs KD-tree, at matched group counts.
 
-Records build-time / ratio-score results — including the round-by-round
-build trajectory and the batch-vs-scalar GetGroup probe parity check — to
+Records build-time / ratio-score results — including the round count and
+each round's seconds, read from the build's ``SpanLog``, and the
+batch-vs-scalar GetGroup probe parity check — to
 ``BENCH_partition.json`` at the repo root so later PRs can track the
 trajectory (same pattern as ``BENCH_lp.json``).
 
@@ -25,6 +26,7 @@ from benchmarks.common import emit, timed
 from repro.core.dlv import dlv_heap, dlv_rounds, ratio_score
 from repro.core.hierarchy import _min_gap
 from repro.core.kdtree import kdtree_partition
+from repro.core.spans import SpanLog
 from repro.data.synth_tables import make_table
 
 BENCH_PATH = Path(__file__).resolve().parent.parent / "BENCH_partition.json"
@@ -67,7 +69,7 @@ def _probe_parity(res, X: np.ndarray, probes: int, seed: int = 1) -> dict:
 def build_entry(n: int, d_f: int, *, heap: bool = True,
                 seed_heap_budget_s: float = 0.0,
                 probes: int = 10_000, seed: int = 0) -> dict:
-    """One benchmark entry: rounds (+trajectory), optional heap baseline
+    """One benchmark entry: rounds (+ seconds a round), optional heap baseline
     (fast shared-scan variant, plus the faithful seed-scan variant under a
     time budget when ``seed_heap_budget_s`` > 0), KD-tree at matched group
     count, and the probe parity record."""
@@ -76,12 +78,18 @@ def build_entry(n: int, d_f: int, *, heap: bool = True,
     X = np.stack([table[a] for a in ATTRS], axis=1)
     entry = {"n": n, "d_f": d_f, "target": n // d_f}
 
-    log: list = []
-    res_r, t_r = timed(dlv_rounds, X, d_f, log=log)
+    spans = SpanLog()
+    res_r, t_r = timed(dlv_rounds, X, d_f, spans=spans)
+    # a round runs from its dlv.sort to the next round's (the last one to
+    # build.finalize)
+    starts = [s.t0_ns for s in spans.spans
+              if s.name in ("dlv.sort", "build.finalize")]
     entry["rounds"] = {"time_s": t_r, "groups": res_r.num_groups,
                        "ratio_score": _mean_ratio(X, res_r.gid),
                        "ratio_score_dominant": _dominant_ratio(X, res_r.gid),
-                       "trajectory": log}
+                       "rounds": int(spans.counters.get("dlv_rounds", 0)),
+                       "round_s": [(b - a) * 1e-9
+                                   for a, b in zip(starts, starts[1:])]}
     emit(f"miniexp5/dlv_rounds/n{n}", t_r * 1e6,
          f"groups={res_r.num_groups};z={entry['rounds']['ratio_score']:.4f}")
 

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from repro.core.partitioner import (Partition, SplitTree, finalize,
                                     register_backend)
+from repro.core.spans import SpanLog, span
 
 # ------------------------------------------------------------- 1-D DLV
 
@@ -594,7 +595,7 @@ def dlv_rounds(X: np.ndarray, d_f: int, *, c: Optional[np.ndarray] = None,
                min_groups: Optional[int] = None,
                rng: Optional[np.random.Generator] = None,
                mesh=None, chunk_rows: Optional[int] = None,
-               log: Optional[list] = None) -> Partition:
+               spans: Optional[SpanLog] = None) -> Partition:
     """Algorithm 6 as batched frontier rounds (the tentpole build).
 
     Every round: (1) rank the frontier by total variance and select the
@@ -603,18 +604,19 @@ def dlv_rounds(X: np.ndarray, d_f: int, *, c: Optional[np.ndarray] = None,
     build's stop rule); (2) concatenate the selected spans and sort them
     with ONE ``np.lexsort`` keyed by (segment, value); (3) place all
     delimiters with ONE segmented scan launch; (4) obtain every child's
-    per-attribute stats from ONE ``segment_stats`` pass.  ``log`` (optional
-    list) receives one dict per round: groups so far, selected count, and
-    new children — the build-time trajectory the partitioning benchmark
-    records.
+    per-attribute stats from ONE ``segment_stats`` pass.  ``spans`` (the
+    build's :class:`~repro.core.spans.SpanLog`) records ``dlv.scale``
+    (Algorithm 7's scale factors), each round's ``dlv.sort`` (gather and
+    per-span sort), ``dlv.cuts`` (delimiter scans with their beta/4
+    retries) and ``dlv.stats`` (children's stats), the
+    ``build.finalize`` tail and the ``dlv_rounds`` counter.
     """
-    import time as _time
-    t0 = _time.time()
     X = np.asarray(X, np.float64)
     n, k = X.shape
     target = min_groups if min_groups is not None else max(1, n // d_f)
     if c is None:
-        c = get_scale_factors(X, d_f, rng=rng)
+        with span(spans, "dlv.scale"):
+            c = get_scale_factors(X, d_f, rng=rng)
     gshift = X.mean(axis=0)
 
     order = np.arange(n)
@@ -643,6 +645,8 @@ def dlv_rounds(X: np.ndarray, d_f: int, *, c: Optional[np.ndarray] = None,
         cand = np.flatnonzero((cnt >= 2) & (tv > 0) & ~frozen)
         if not len(cand):
             break
+        if spans is not None:
+            spans.add("dlv_rounds")
         remaining = target - len(S)
         take = max(1, int(np.ceil(remaining / max(avg_children - 1.0, 1.0))))
         if len(cand) > take:
@@ -650,49 +654,51 @@ def dlv_rounds(X: np.ndarray, d_f: int, *, c: Optional[np.ndarray] = None,
             sel = cand[np.argpartition(-tv[cand], take - 1)[:take]]
         else:
             sel = cand
-        nseg = len(sel)
-        Ls = (E - S)[sel]
-        total = int(Ls.sum())
-        seg_off = np.concatenate([[0], np.cumsum(Ls)])
-        segid = np.repeat(np.arange(nseg), Ls)
-        base = np.repeat(S[sel] - seg_off[:-1], Ls)
-        pos = base + np.arange(total)              # order slots, per segment
-        idxc = order[pos]
-        jel = np.repeat(jbest[sel], Ls)
-        vals = X[idxc, jel]
-        # segmented sort: per-span stable argsort into one permutation
-        # (beats a 2-key lexsort ~10x — span slices are contiguous)
-        perm = np.empty(total, np.int64)
-        for si in range(nseg):
-            a, b = seg_off[si], seg_off[si + 1]
-            perm[a:b] = a + np.argsort(vals[a:b], kind="stable")
-        idxs = idxc[perm]
-        vals_s = vals[perm]
+        with span(spans, "dlv.sort"):
+            nseg = len(sel)
+            Ls = (E - S)[sel]
+            total = int(Ls.sum())
+            seg_off = np.concatenate([[0], np.cumsum(Ls)])
+            segid = np.repeat(np.arange(nseg), Ls)
+            base = np.repeat(S[sel] - seg_off[:-1], Ls)
+            pos = base + np.arange(total)          # order slots, per segment
+            idxc = order[pos]
+            jel = np.repeat(jbest[sel], Ls)
+            vals = X[idxc, jel]
+            # segmented sort: per-span stable argsort into one permutation
+            # (beats a 2-key lexsort ~10x — span slices are contiguous)
+            perm = np.empty(total, np.int64)
+            for si in range(nseg):
+                a, b = seg_off[si], seg_off[si + 1]
+                perm[a:b] = a + np.argsort(vals[a:b], kind="stable")
+            idxs = idxc[perm]
+            vals_s = vals[perm]
 
-        # per-segment center (raw partition mean on the split attribute)
-        mean_sel = SU[sel, jbest[sel]] / Ls + gshift[jbest[sel]]
-        beta_sel = c[jbest[sel]] * vmax[sel] / (d_f * d_f)
-        reset = np.zeros(total, bool)
-        reset[seg_off[:-1]] = True
-        vs = vals_s - np.repeat(mean_sel, Ls)
-        cuts = _seg_cuts(vs, Ls, beta_sel, pitch=d_f)
+        with span(spans, "dlv.cuts"):
+            # per-segment center (raw partition mean on the split attribute)
+            mean_sel = SU[sel, jbest[sel]] / Ls + gshift[jbest[sel]]
+            beta_sel = c[jbest[sel]] * vmax[sel] / (d_f * d_f)
+            reset = np.zeros(total, bool)
+            reset[seg_off[:-1]] = True
+            vs = vals_s - np.repeat(mean_sel, Ls)
+            cuts = _seg_cuts(vs, Ls, beta_sel, pitch=d_f)
 
-        # segments that produced no delimiter retry with beta/4 (the heap
-        # build's rule); all-equal segments can never split -> frozen
-        ncuts = np.bincount(segid[cuts], minlength=nseg)
-        alleq = vals_s[seg_off[1:] - 1] == vals_s[seg_off[:-1]]
-        fail = np.flatnonzero((ncuts == 0) & ~alleq)
-        tries = 0
-        while len(fail) and tries < 30:
-            beta_sel[fail] *= 0.25
-            fmask = np.zeros(nseg, bool)
-            fmask[fail] = True
-            elm = fmask[segid]
-            cuts[elm] = _seg_cuts(vs[elm], Ls[fail], beta_sel[fail],
-                                  pitch=d_f)
+            # segments that produced no delimiter retry with beta/4 (the heap
+            # build's rule); all-equal segments can never split -> frozen
             ncuts = np.bincount(segid[cuts], minlength=nseg)
+            alleq = vals_s[seg_off[1:] - 1] == vals_s[seg_off[:-1]]
             fail = np.flatnonzero((ncuts == 0) & ~alleq)
-            tries += 1
+            tries = 0
+            while len(fail) and tries < 30:
+                beta_sel[fail] *= 0.25
+                fmask = np.zeros(nseg, bool)
+                fmask[fail] = True
+                elm = fmask[segid]
+                cuts[elm] = _seg_cuts(vs[elm], Ls[fail], beta_sel[fail],
+                                      pitch=d_f)
+                ncuts = np.bincount(segid[cuts], minlength=nseg)
+                fail = np.flatnonzero((ncuts == 0) & ~alleq)
+                tries += 1
 
         order[pos] = idxs                          # spans are now sorted
         split = np.flatnonzero(ncuts > 0)
@@ -758,12 +764,13 @@ def dlv_rounds(X: np.ndarray, d_f: int, *, c: Optional[np.ndarray] = None,
         # zero placeholders are provably never ranked) skips the pass and
         # lets finalize recompute exact reps
         done = int(keep.sum()) + len(ch_sel) >= target
-        if done:
-            csum = np.zeros((n_children, k))
-            csq = np.zeros((n_children, k))
-        else:
-            _, csum, csq = _segment_stats_auto(X[idxs] - gshift, cid,
-                                               n_children)
+        with span(spans, "dlv.stats"):
+            if done:
+                csum = np.zeros((n_children, k))
+                csq = np.zeros((n_children, k))
+            else:
+                _, csum, csq = _segment_stats_auto(X[idxs] - gshift, cid,
+                                                   n_children)
         ch_S = child_start[ch_sel]
         S = np.concatenate([S[keep], ch_S])
         E = np.concatenate([E[keep], ch_S + ch_cnt])
@@ -772,24 +779,20 @@ def dlv_rounds(X: np.ndarray, d_f: int, *, c: Optional[np.ndarray] = None,
         frozen = np.concatenate([frozen[keep], ch_cnt <= 1])
         pid = np.concatenate([pid[keep], ch_pid])
         avg_children = len(ch_sel) / max(len(split), 1)
-        if log is not None:
-            log.append({"round": len(log), "groups": int(len(S)),
-                        "selected": int(nseg), "split": int(len(split)),
-                        "children": int(len(ch_sel)),
-                        "t": _time.time() - t0})
         if done:
             break
 
-    # finalize: groups in slice order, unresolved leaf pids -> ~gid
-    gorder = np.argsort(S, kind="stable")
-    offsets = np.concatenate([S[gorder], [n]])
-    pid_to_gid = {int(pid[r]): g for g, r in enumerate(gorder)}
-    for node in nodes:
-        node.children = [
-            ~pid_to_gid[ch - _PID_TAG] if ch >= _PID_TAG else ch
-            for ch in node.children]
-    return finalize(X, order, offsets, _tree_from_nodes(nodes, root),
-                    mesh=mesh, chunk_rows=chunk_rows)
+    with span(spans, "build.finalize"):
+        # groups in slice order, unresolved leaf pids -> ~gid
+        gorder = np.argsort(S, kind="stable")
+        offsets = np.concatenate([S[gorder], [n]])
+        pid_to_gid = {int(pid[r]): g for g, r in enumerate(gorder)}
+        for node in nodes:
+            node.children = [
+                ~pid_to_gid[ch - _PID_TAG] if ch >= _PID_TAG else ch
+                for ch in node.children]
+        return finalize(X, order, offsets, _tree_from_nodes(nodes, root),
+                        mesh=mesh, chunk_rows=chunk_rows)
 
 
 # ------------------------------------------------------------- entry point
@@ -799,11 +802,13 @@ def dlv_rounds(X: np.ndarray, d_f: int, *, c: Optional[np.ndarray] = None,
 def dlv(X: np.ndarray, d_f: int = 100, *, c: Optional[np.ndarray] = None,
         min_groups: Optional[int] = None,
         rng: Optional[np.random.Generator] = None,
-        method: str = "rounds", **kwargs) -> Partition:
-    """Algorithm 6 over tuples X (n, k); produces ~n/d_f groups."""
+        method: str = "rounds", spans: Optional[SpanLog] = None,
+        **kwargs) -> Partition:
+    """Algorithm 6 over tuples X (n, k); produces ~n/d_f groups.
+    ``spans`` receives the rounds' phases (the heap build has none)."""
     if method == "rounds":
         return dlv_rounds(X, d_f, c=c, min_groups=min_groups, rng=rng,
-                          **kwargs)
+                          spans=spans, **kwargs)
     if method == "heap":
         # forward everything: unknown options raise instead of silently
         # configuring nothing

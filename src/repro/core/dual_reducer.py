@@ -25,6 +25,7 @@ from repro.core.lp import INFEASIBLE, OPTIMAL, LPResult, WarmStart, \
     fill_warm_basis, solve_lp_np
 from repro.core.lp_batch import solve_lp_batch
 from repro.core.paql import PackageQuery
+from repro.core.spans import span
 
 
 @dataclasses.dataclass
@@ -39,7 +40,6 @@ class PackageResult:
     status: str = ""
     report: Optional[object] = None   # guard.SolveReport (engine.solve)
     lp_warm: Optional[WarmStart] = None   # lp1 final basis (cache artifact)
-    ps_stats: Optional[object] = None     # shading.PSStats (cascade solves)
 
     def integrality_gap(self, eps: float = 0.1) -> float:
         """Paper §4.1 metric vs. this result's own LP bound."""
@@ -105,57 +105,59 @@ def dual_reducer(query: PackageQuery, table, S: np.ndarray, *, q: int = 500,
     monitor = report.monitor if report is not None else None
     S = np.asarray(S)
     n = len(S)
-    c, A, bl, bu, ub = query.matrices(table, S)
+    log = report.spans if report is not None else None
+    with span(log, "dr.lp"):
+        c, A, bl, bu, ub = query.matrices(table, S)
 
-    lp1 = solve_lp_np(c, A, bl, bu, ub, max_iters=max_lp_iters,
-                      warm_start=warm_start, budget=budget,
-                      monitor=monitor)
-    if report is not None:
-        report.absorb_lp(lp1)
-    if lp1.status == INFEASIBLE and ladder:
-        # tight queries can be declared infeasible by a hair: retry warm
-        # with a relaxed tolerance before giving up (ladder rung 1)
         lp1 = solve_lp_np(c, A, bl, bu, ub, max_iters=max_lp_iters,
-                          tol=1e-5, warm_start=lp1, budget=budget,
+                          warm_start=warm_start, budget=budget,
                           monitor=monitor)
         if report is not None:
-            report.rung("dr_relax_tol",
-                        detail=f"retry status={lp1.status}")
             report.absorb_lp(lp1)
-    if lp1.status != OPTIMAL:
-        status = "lp_budget" if lp1.status == ilp_mod.BUDGET \
-            else "lp_infeasible"
-        return PackageResult(False, np.zeros(0, np.int64), np.zeros(0),
-                             0.0, 0.0, status=status)
-    lp_obj_query = -lp1.obj if query.maximize else lp1.obj
+        if lp1.status == INFEASIBLE and ladder:
+            # tight queries can be declared infeasible by a hair: retry warm
+            # with a relaxed tolerance before giving up (ladder rung 1)
+            lp1 = solve_lp_np(c, A, bl, bu, ub, max_iters=max_lp_iters,
+                              tol=1e-5, warm_start=lp1, budget=budget,
+                              monitor=monitor)
+            if report is not None:
+                report.rung("dr_relax_tol",
+                            detail=f"retry status={lp1.status}")
+                report.absorb_lp(lp1)
+        if lp1.status != OPTIMAL:
+            status = "lp_budget" if lp1.status == ilp_mod.BUDGET \
+                else "lp_infeasible"
+            return PackageResult(False, np.zeros(0, np.int64), np.zeros(0),
+                                 0.0, 0.0, status=status)
+        lp_obj_query = -lp1.obj if query.maximize else lp1.obj
 
-    tol = 1e-9
-    support = lp1.x > tol
-    aux_supports = []          # precomputed widening rungs (fallback rounds)
-    if aux == "random":
-        support |= rng.random(n) < q / max(n, 1)
-    else:
-        E = float(np.sum(lp1.x))
-        rungs = max(1, int(aux_rungs))
-        # rung j caps every variable at E/(q*2^j): the support the
-        # exponential fallback would need after j doublings of q.  All
-        # rungs are bound-variants of one (c, A) warm-started from lp1:
-        # one batched dispatch (sequential solve_lp_np when rungs == 1).
-        ub_variants = [np.minimum(ub, max(E / (max(q, 1) * 2 ** j), 1e-9))
-                       for j in range(rungs)]
-        auxs = solve_lp_batch(c, A, bl, bu, ub_variants,
-                              max_iters=max_lp_iters,
-                              warm_starts=[lp1] * rungs, budget=budget,
-                              monitor=monitor, backend=batch_backend)
-        if report is not None:
-            report.absorb_batch(auxs)
-        for jr, lp2 in enumerate(auxs):
-            if lp2.status != OPTIMAL:
-                continue
-            if jr == 0:
-                support |= lp2.x > tol
-            else:
-                aux_supports.append(lp2.x > tol)
+        tol = 1e-9
+        support = lp1.x > tol
+        aux_supports = []    # precomputed widening rungs (fallback rounds)
+        if aux == "random":
+            support |= rng.random(n) < q / max(n, 1)
+        else:
+            E = float(np.sum(lp1.x))
+            rungs = max(1, int(aux_rungs))
+            # rung j caps every variable at E/(q*2^j): the support the
+            # exponential fallback would need after j doublings of q.  All
+            # rungs are bound-variants of one (c, A) warm-started from lp1:
+            # one batched dispatch (sequential solve_lp_np when rungs == 1).
+            ub_variants = [np.minimum(ub, max(E / (max(q, 1) * 2 ** j), 1e-9))
+                           for j in range(rungs)]
+            auxs = solve_lp_batch(c, A, bl, bu, ub_variants,
+                                  max_iters=max_lp_iters,
+                                  warm_starts=[lp1] * rungs, budget=budget,
+                                  monitor=monitor, backend=batch_backend)
+            if report is not None:
+                report.absorb_batch(auxs)
+            for jr, lp2 in enumerate(auxs):
+                if lp2.status != OPTIMAL:
+                    continue
+                if jr == 0:
+                    support |= lp2.x > tol
+                else:
+                    aux_supports.append(lp2.x > tol)
     sel = np.flatnonzero(support)
 
     def _degraded_rounding(n_sel: int, fallbacks: int, why: str):
@@ -179,9 +181,12 @@ def dual_reducer(query: PackageQuery, table, S: np.ndarray, *, q: int = 500,
         res = ilp_mod.solve_ilp(cs, As, bl, bu, ubs,
                                 warm_start=_subset_warm(lp1, sel, n),
                                 budget=budget, monitor=monitor,
-                                **ilp_kwargs)
+                                report=report, **ilp_kwargs)
         if report is not None:
             report.ilp_nodes += res.nodes
+            report.ilp_lp_pivots += res.lp_iters
+            report.ilp_capped += res.status in (ilp_mod.ILP_FEASIBLE,
+                                                ilp_mod.ILP_LIMIT)
         if res.feasible:
             mult = res.x
             nz = mult > 0.5

@@ -54,7 +54,6 @@ class PackageQueryEngine:
         self.mesh = mesh
         self.rng = np.random.default_rng(seed)
         self.hierarchy: Optional[Hierarchy] = None
-        self.partition_time_s: float = 0.0
         # cross-query artifact cache: True -> a private QCache; or pass a
         # QCache instance shared across engines (the serving-layer shape)
         if cache is True:
@@ -84,8 +83,13 @@ class PackageQueryEngine:
         s.rng = np.random.default_rng(seed)
         return s
 
+    @property
+    def partition_time_s(self) -> float:
+        """Seconds of the hierarchy build (its ``build`` span); 0 before."""
+        return self.hierarchy.spans.seconds("build") \
+            if self.hierarchy is not None else 0.0
+
     def partition(self) -> "PackageQueryEngine":
-        t0 = time.time()
         self.hierarchy = Hierarchy(self.table, self.attrs, d_f=self.d_f,
                                    alpha=self.alpha, rng=self.rng,
                                    backend=self.partitioner_backend,
@@ -93,7 +97,6 @@ class PackageQueryEngine:
                                    chunk_rows=self.chunk_rows,
                                    memory_rows=self.memory_rows,
                                    mesh=self.mesh)
-        self.partition_time_s = time.time() - t0
         return self
 
     # ------------------------------------------------------------ solvers
@@ -126,23 +129,31 @@ class PackageQueryEngine:
                                    monitor=guard.NumericalMonitor())
         report.budget.start()
         io0 = io_retry_count()
-        try:
-            res = progressive_shading(self.hierarchy, query, self.table,
-                                      alpha=self.alpha, dr_q=dr_q,
-                                      rng=self.rng, ilp_kwargs=ilp_kwargs,
-                                      budget=report.budget, report=report,
-                                      ladder=guarded, qcache=self.cache,
-                                      **ps_kwargs)
-        # repro: allow[REPRO004] guard contract: guarded solve must never
-        # raise -- contain, report, and return an empty (infeasible) result
-        except Exception as e:
-            if not guarded:
-                raise
-            # guard contract: never raise — contain, report, return empty
-            report.status = guard.ERROR
-            report.note(f"error: {type(e).__name__}: {e}")
-            res = PackageResult(False, np.zeros(0, np.int64), np.zeros(0),
-                                0.0, 0.0, status="error")
+        with report.spans.span("solve") as ann:
+            try:
+                res = progressive_shading(self.hierarchy, query, self.table,
+                                          alpha=self.alpha, dr_q=dr_q,
+                                          rng=self.rng,
+                                          ilp_kwargs=ilp_kwargs,
+                                          budget=report.budget,
+                                          report=report, ladder=guarded,
+                                          qcache=self.cache, **ps_kwargs)
+            # repro: allow[REPRO004] guard contract: guarded solve must
+            # never raise -- contain, report, and return an empty
+            # (infeasible) result
+            except Exception as e:
+                if not guarded:
+                    raise
+                # guard contract: never raise — contain, report, return
+                # empty
+                report.status = guard.ERROR
+                report.note(f"error: {type(e).__name__}: {e}")
+                res = PackageResult(False, np.zeros(0, np.int64),
+                                    np.zeros(0), 0.0, 0.0, status="error")
+            # the counters ride on the trace's pq.solve event
+            ann.set_metadata(ilp_lp_pivots=report.ilp_lp_pivots,
+                             ilp_node_lp_s=report.ilp_node_lp_s,
+                             ilp_capped=report.ilp_capped)
         report.fault_retries = io_retry_count() - io0
         res.report = report.finalize(res.feasible)
         res.status += f" t={time.time() - t0:.3f}s"

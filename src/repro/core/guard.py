@@ -50,6 +50,8 @@ from typing import List, Optional
 
 import numpy as np
 
+from repro.core.spans import SpanLog
+
 # ------------------------------------------------------------- statuses
 
 OK = "ok"
@@ -213,6 +215,9 @@ class SolveReport:
     lp_truncated: int = 0     # LPs that hit an iteration/pivot/time cap
     lp_batches: int = 0       # batched dispatches (core.lp_batch flights)
     ilp_nodes: int = 0
+    ilp_lp_pivots: int = 0    # B&B root and node LP pivots (lp_iters)
+    ilp_node_lp_s: float = 0.0  # seconds in B&B's node LP solves
+    ilp_capped: int = 0       # sub-ILPs stopped at a node/time/budget cap
     fault_retries: int = 0
     wall_s: float = 0.0
     warm_rejected: int = 0    # cascade warm-basis re-maps that fell cold
@@ -220,6 +225,9 @@ class SolveReport:
     cache_hits: int = 0
     cache_misses: int = 0
     cache_pruned_lps: int = 0  # layer LPs skipped thanks to cached sets
+    cache_kind: str = ""      # "" | "package" | "exact" | "contained"
+    # the solve's phases (engine.solve, shading, dual_reducer, solve_ilp)
+    spans: SpanLog = dataclasses.field(default_factory=SpanLog, repr=False)
 
     def note(self, msg: str) -> None:
         self.notes.append(str(msg))

@@ -41,6 +41,7 @@ import numpy as np
 from repro.core import partitioner
 from repro.core.partitioner import Partition
 from repro.core.relation import Relation, as_relation
+from repro.core.spans import SpanLog
 
 _EXACT_GAP_LIMIT = 2_000_000
 _GAP_SAMPLE = 200_000
@@ -102,75 +103,82 @@ class Hierarchy:
                  backend_kwargs: Optional[dict] = None,
                  mesh=None, chunk_rows: Optional[int] = None,
                  memory_rows: Optional[int] = None):
-        self.attrs = list(attrs)
-        self.d_f = d_f
-        self.alpha = alpha
-        self.backend = backend
-        rng = rng or np.random.default_rng(0)
-        rel = as_relation(table, columns=self.attrs)
-        self.relation = rel
-        if layer0_backend is None:
-            # streamed relations default layer 0 to the one chunk-capable
-            # backend; upper layers (rep arrays) keep ``backend``
-            layer0_backend = "bucketing" \
-                if (not rel.in_memory and backend == "dlv") else backend
-        if not rel.in_memory and layer0_backend != "bucketing":
-            raise TypeError(
-                f"partitioner backend {layer0_backend!r} cannot consume a "
-                "streamed relation (only 'bucketing' scans ChunkSources); "
-                "pass an in-memory table or layer0_backend='bucketing'")
-        self.layer0_backend = layer0_backend
-        if rel.in_memory:
-            # repro: allow[REPRO005] guarded by rel.in_memory: columns
-            # are already resident; this is a view stack, not a load
-            X0 = np.stack([np.asarray(rel[a], np.float64)
-                           for a in self.attrs], axis=1)
-            self.layers: List[Layer] = [
-                Layer(rel, X0, None, _min_gap(X0, rng=rng))]
-        else:
-            # layer-0 eps is never consumed (Neighbor Sampling probes only
-            # layers >= 1), so a streamed build skips the sample gather
-            self.layers = [Layer(rel, None, None, 1e-9)]
-        kw = dict(backend_kwargs or {})
-        self._append_state: Optional[dict] = None
-        self._fingerprint: Optional[str] = None
-        self._invalidation_hooks: List[Callable] = []
-        while self.layers[-1].size > alpha and len(self.layers) <= max_layers:
-            if len(self.layers) == 1 and not rel.in_memory:
-                # streamed layer 0: the bucketing backend consumes the
-                # relation chunk-by-chunk (Appendix D.2) — the attribute
-                # matrix never materialises
-                layer_kw = dict(kw)
-                if memory_rows is not None:
-                    layer_kw.setdefault("memory_rows", memory_rows)
-                if chunk_rows is not None:
-                    layer_kw.setdefault("chunk_rows", chunk_rows)
-                if mesh is not None:
-                    layer_kw.setdefault("mesh", mesh)
-                part = partitioner.fit(
-                    rel.chunk_source(self.attrs, chunk_rows),
-                    backend=layer0_backend, d_f=d_f, rng=rng, **layer_kw)
+        # the build's phases: ``build`` here, the DLV rounds' phases and
+        # ``build.finalize`` inside the dlv backend
+        self.spans = SpanLog()
+        with self.spans.span("build"):
+            self.attrs = list(attrs)
+            self.d_f = d_f
+            self.alpha = alpha
+            self.backend = backend
+            rng = rng or np.random.default_rng(0)
+            rel = as_relation(table, columns=self.attrs)
+            self.relation = rel
+            if layer0_backend is None:
+                # streamed relations default layer 0 to the one chunk-capable
+                # backend; upper layers (rep arrays) keep ``backend``
+                layer0_backend = "bucketing" \
+                    if (not rel.in_memory and backend == "dlv") else backend
+            if not rel.in_memory and layer0_backend != "bucketing":
+                raise TypeError(
+                    f"partitioner backend {layer0_backend!r} cannot consume a "
+                    "streamed relation (only 'bucketing' scans ChunkSources); "
+                    "pass an in-memory table or layer0_backend='bucketing'")
+            self.layer0_backend = layer0_backend
+            if rel.in_memory:
+                # repro: allow[REPRO005] guarded by rel.in_memory: columns
+                # are already resident; this is a view stack, not a load
+                X0 = np.stack([np.asarray(rel[a], np.float64)
+                               for a in self.attrs], axis=1)
+                self.layers: List[Layer] = [
+                    Layer(rel, X0, None, _min_gap(X0, rng=rng))]
             else:
-                Xl = self.layers[-1].X
-                lb = layer0_backend if len(self.layers) == 1 else backend
-                layer_kw = dict(kw)
-                if len(self.layers) == 1 and chunk_rows is not None:
-                    # layer 0 is the big one: chunked (optionally mesh-
-                    # sharded) group-stats accumulation instead of a full
-                    # sorted copy
-                    layer_kw.update(chunk_rows=chunk_rows, mesh=mesh)
-                if len(self.layers) == 1 and lb == "bucketing" and \
-                        memory_rows is not None:
-                    # same bucket layout as the streamed path -> in-memory
-                    # and memmap builds of the same data stay bit-identical
-                    layer_kw.setdefault("memory_rows", memory_rows)
-                part = partitioner.fit(Xl, backend=lb, d_f=d_f,
-                                       rng=rng, **layer_kw)
-            if part.num_groups >= self.layers[-1].size:
-                break  # no reduction possible
-            reps = part.reps
-            tbl = {a: reps[:, i] for i, a in enumerate(self.attrs)}
-            self.layers.append(Layer(tbl, reps, part, _min_gap(reps)))
+                # layer-0 eps is never consumed (Neighbor Sampling probes only
+                # layers >= 1), so a streamed build skips the sample gather
+                self.layers = [Layer(rel, None, None, 1e-9)]
+            kw = dict(backend_kwargs or {})
+            self._append_state: Optional[dict] = None
+            self._fingerprint: Optional[str] = None
+            self._invalidation_hooks: List[Callable] = []
+            while self.layers[-1].size > alpha \
+                    and len(self.layers) <= max_layers:
+                if len(self.layers) == 1 and not rel.in_memory:
+                    # streamed layer 0: the bucketing backend consumes the
+                    # relation chunk-by-chunk (Appendix D.2) — the attribute
+                    # matrix never materialises
+                    layer_kw = dict(kw)
+                    if memory_rows is not None:
+                        layer_kw.setdefault("memory_rows", memory_rows)
+                    if chunk_rows is not None:
+                        layer_kw.setdefault("chunk_rows", chunk_rows)
+                    if mesh is not None:
+                        layer_kw.setdefault("mesh", mesh)
+                    part = partitioner.fit(
+                        rel.chunk_source(self.attrs, chunk_rows),
+                        backend=layer0_backend, d_f=d_f, rng=rng, **layer_kw)
+                else:
+                    Xl = self.layers[-1].X
+                    lb = layer0_backend if len(self.layers) == 1 else backend
+                    layer_kw = dict(kw)
+                    if len(self.layers) == 1 and chunk_rows is not None:
+                        # layer 0 is the big one: chunked (optionally mesh-
+                        # sharded) group-stats accumulation instead of a full
+                        # sorted copy
+                        layer_kw.update(chunk_rows=chunk_rows, mesh=mesh)
+                    if lb == "dlv":
+                        layer_kw["spans"] = self.spans
+                    if len(self.layers) == 1 and lb == "bucketing" and \
+                            memory_rows is not None:
+                        # same bucket layout as the streamed path -> in-memory
+                        # and memmap builds of the same data stay bit-identical
+                        layer_kw.setdefault("memory_rows", memory_rows)
+                    part = partitioner.fit(Xl, backend=lb, d_f=d_f,
+                                           rng=rng, **layer_kw)
+                if part.num_groups >= self.layers[-1].size:
+                    break  # no reduction possible
+                reps = part.reps
+                tbl = {a: reps[:, i] for i, a in enumerate(self.attrs)}
+                self.layers.append(Layer(tbl, reps, part, _min_gap(reps)))
 
     @property
     def L(self) -> int:

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro.core.lp import solve_lp_np, BUDGET, OPTIMAL, INFEASIBLE
 from repro.core.lp_batch import solve_lp_batch
+from repro.core.spans import span
 
 ILP_OPTIMAL, ILP_FEASIBLE, ILP_INFEASIBLE, ILP_LIMIT = 0, 1, 2, 3
 
@@ -227,12 +228,50 @@ def _feasibility_pump(c, A, bl, bu, lb, ub, tol, max_lp_iters,
     return None, np.inf
 
 
+def _incumbent(root, c, A, bl, bu, lb, ub, tol, max_lp_iters, *,
+               budget=None, probe_batch: bool = False):
+    """A first integer point from the root relaxation, or (None, inf):
+    rounding, swap repair, randomized-rounding restarts, diving and the
+    feasibility pump in turn, then a final swap improvement."""
+    m, n = A.shape
+    best_x, best_obj = _round_feasible(root.x, c, A, bl, bu, lb, ub, tol)
+    if best_x is None:
+        # swap-based repair + improvement from the rounded LP point
+        best_x, best_obj = _swap_search(root.x, c, A, bl, bu, lb, ub, tol)
+    if best_x is None:
+        # randomized-rounding restarts escape repair local minima
+        rng = np.random.default_rng(7)
+        for _ in range(8):
+            frac = root.x - np.floor(root.x)
+            xr = np.floor(root.x) + (rng.random(n) < frac)
+            jitter = rng.random(n) < (3.0 / max(n, 1))
+            xr = np.clip(xr + jitter * rng.integers(-1, 2, n), lb, ub)
+            bx, bo = _swap_search(xr, c, A, bl, bu, lb, ub, tol)
+            if bx is not None:
+                best_x, best_obj = bx, bo
+                break
+    if best_x is None:
+        best_x, best_obj = _dive(c, A, bl, bu, lb, ub, tol, max_lp_iters,
+                                 max_steps=4 * m + 8, warm_start=root,
+                                 budget=budget, probe_batch=probe_batch)
+    if best_x is None:
+        best_x, best_obj = _feasibility_pump(c, A, bl, bu, lb, ub, tol,
+                                             max_lp_iters, warm_start=root,
+                                             budget=budget)
+    if best_x is not None:
+        bx, bo = _swap_search(best_x, c, A, bl, bu, lb, ub, tol)
+        if bx is not None and bo < best_obj:
+            best_x, best_obj = bx, bo
+    return best_x, best_obj
+
+
 def solve_ilp(c, A, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
               max_nodes: int = 5000, tol: float = 1e-6,
               time_limit_s: float = 60.0, max_lp_iters: int = 8000,
               warm_start=None, warm_nodes: bool = True,
               budget=None, monitor=None, wave_width: int = 1,
-              batch_backend: Optional[str] = None) -> ILPResult:
+              batch_backend: Optional[str] = None,
+              report=None) -> ILPResult:
     """warm_nodes=False disables node-LP warm starting (benchmark knob).
 
     ``budget=`` (a ``guard.SolveBudget``) clamps the node/time limits to
@@ -251,10 +290,14 @@ def solve_ilp(c, A, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
     other before solving) for one dispatch per wave.  ``batch_backend``
     overrides the engine choice (default: ``"np"`` for W=1, ``"auto"``
     otherwise).
+
+    ``report=`` (a ``guard.SolveReport``) records the spans
+    ``ilp.incumbent`` (root LP and heuristics) and ``ilp.search`` (the
+    node loop) and adds the node LPs' seconds to ``ilp_node_lp_s``.
     """
     c = np.asarray(c, np.float64)
     A = np.atleast_2d(np.asarray(A, np.float64))
-    m, n = A.shape
+    n = A.shape[1]
     bl = np.asarray(bl, np.float64)
     bu = np.asarray(bu, np.float64)
     ub0 = np.asarray(ub, np.float64)
@@ -267,57 +310,32 @@ def solve_ilp(c, A, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
         time_limit_s = kw["time_limit_s"]
         max_nodes = kw["max_nodes"]
 
-    root = solve_lp_np(c, A, bl, bu, ub0, lb=lb0, max_iters=max_lp_iters,
-                       warm_start=warm_start, budget=budget,
-                       monitor=monitor)
-    lp_iters = root.iters
-    if root.status == INFEASIBLE:
-        return ILPResult(ILP_INFEASIBLE, np.zeros(n), np.inf, 1, np.inf,
-                         lp_iters)
-    root_obj = root.obj
-    if root.status == BUDGET:
-        # truncated root relaxation: salvage an incumbent by rounding the
-        # (possibly primal-infeasible) iterate, skip the search
-        best_x, best_obj = _round_feasible(root.x, c, A, bl, bu, lb0, ub0,
-                                           tol)
-        if best_x is None:
-            best_x, best_obj = _swap_search(root.x, c, A, bl, bu, lb0,
-                                            ub0, tol)
-        if best_x is None:
-            return ILPResult(ILP_LIMIT, np.zeros(n), np.inf, 0, root_obj,
+    log = report.spans if report is not None else None
+    with span(log, "ilp.incumbent"):
+        root = solve_lp_np(c, A, bl, bu, ub0, lb=lb0, max_iters=max_lp_iters,
+                           warm_start=warm_start, budget=budget,
+                           monitor=monitor)
+        lp_iters = root.iters
+        if root.status == INFEASIBLE:
+            return ILPResult(ILP_INFEASIBLE, np.zeros(n), np.inf, 1, np.inf,
                              lp_iters)
-        return ILPResult(ILP_FEASIBLE, best_x, best_obj, 0, root_obj,
-                         lp_iters)
-
-    best_x, best_obj = _round_feasible(root.x, c, A, bl, bu, lb0, ub0, tol)
-    if best_x is None:
-        # swap-based repair + improvement from the rounded LP point
-        best_x, best_obj = _swap_search(root.x, c, A, bl, bu, lb0, ub0, tol)
-    if best_x is None:
-        # randomized-rounding restarts escape repair local minima
-        rng = np.random.default_rng(7)
-        for _ in range(8):
-            frac = root.x - np.floor(root.x)
-            xr = np.floor(root.x) + (rng.random(n) < frac)
-            jitter = rng.random(n) < (3.0 / max(n, 1))
-            xr = np.clip(xr + jitter * rng.integers(-1, 2, n), lb0, ub0)
-            bx, bo = _swap_search(xr, c, A, bl, bu, lb0, ub0, tol)
-            if bx is not None:
-                best_x, best_obj = bx, bo
-                break
-    if best_x is None:
-        best_x, best_obj = _dive(c, A, bl, bu, lb0, ub0, tol, max_lp_iters,
-                                 max_steps=4 * m + 8, warm_start=root,
-                                 budget=budget,
-                                 probe_batch=wave_width > 1)
-    if best_x is None:
-        best_x, best_obj = _feasibility_pump(c, A, bl, bu, lb0, ub0, tol,
-                                             max_lp_iters, warm_start=root,
-                                             budget=budget)
-    if best_x is not None:
-        bx, bo = _swap_search(best_x, c, A, bl, bu, lb0, ub0, tol)
-        if bx is not None and bo < best_obj:
-            best_x, best_obj = bx, bo
+        root_obj = root.obj
+        if root.status == BUDGET:
+            # truncated root relaxation: salvage an incumbent by rounding the
+            # (possibly primal-infeasible) iterate, skip the search
+            best_x, best_obj = _round_feasible(root.x, c, A, bl, bu, lb0, ub0,
+                                               tol)
+            if best_x is None:
+                best_x, best_obj = _swap_search(root.x, c, A, bl, bu, lb0,
+                                                ub0, tol)
+            if best_x is None:
+                return ILPResult(ILP_LIMIT, np.zeros(n), np.inf, 0, root_obj,
+                                 lp_iters)
+            return ILPResult(ILP_FEASIBLE, best_x, best_obj, 0, root_obj,
+                             lp_iters)
+        best_x, best_obj = _incumbent(root, c, A, bl, bu, lb0, ub0, tol,
+                                      max_lp_iters, budget=budget,
+                                      probe_batch=wave_width > 1)
 
     heap = []
     counter = itertools.count()
@@ -329,89 +347,93 @@ def solve_ilp(c, A, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
     wave_width = max(1, int(wave_width))
     if batch_backend is None:
         batch_backend = "np" if wave_width == 1 else "auto"
-    while heap:
-        # ---- gather one frontier wave: up to W best-bound expansions ----
-        wave_specs = []       # (lb2, ub2, parent warm-start)
-        expanded = 0
-        limit = False
-        while heap and expanded < wave_width:
-            if nodes >= max_nodes or (time.time() - t0) > time_limit_s or \
-                    (budget is not None and budget.exhausted()):
-                limit = True
+    with span(log, "ilp.search"):
+        while heap:
+            # ---- gather one frontier wave: up to W best-bound expansions ----
+            wave_specs = []       # (lb2, ub2, parent warm-start)
+            expanded = 0
+            limit = False
+            while heap and expanded < wave_width:
+                if nodes >= max_nodes or (time.time() - t0) > time_limit_s or \
+                        (budget is not None and budget.exhausted()):
+                    limit = True
+                    break
+                bound, _, lbn, ubn, xlp, node_warm = heapq.heappop(heap)
+                if bound >= best_obj - 1e-9:
+                    continue
+                nodes += 1
+                if budget is not None:
+                    budget.charge_nodes(1)
+                frac = np.abs(xlp - np.round(xlp))
+                j = int(np.argmax(frac))
+                if frac[j] < tol:
+                    # integral LP solution: new incumbent
+                    xi = np.round(xlp)
+                    obj = float(c @ xi)
+                    if obj < best_obj:
+                        best_obj, best_x = obj, xi
+                    continue
+                expanded += 1
+                fl = np.floor(xlp[j])
+                for lo_j, hi_j in ((lbn[j], fl), (fl + 1, ubn[j])):
+                    if lo_j > hi_j:
+                        continue
+                    lb2, ub2 = lbn.copy(), ubn.copy()
+                    lb2[j], ub2[j] = lo_j, hi_j
+                    # child differs from parent in one variable's bounds
+                    # only: warm-start the dual simplex from the parent basis
+                    wave_specs.append(
+                        (lb2, ub2, node_warm if warm_nodes else None))
+            if limit and not wave_specs:
+                status = ILP_LIMIT
                 break
-            bound, _, lbn, ubn, xlp, node_warm = heapq.heappop(heap)
-            if bound >= best_obj - 1e-9:
-                continue
-            nodes += 1
-            if budget is not None:
-                budget.charge_nodes(1)
-            frac = np.abs(xlp - np.round(xlp))
-            j = int(np.argmax(frac))
-            if frac[j] < tol:
-                # integral LP solution: new incumbent
-                xi = np.round(xlp)
-                obj = float(c @ xi)
-                if obj < best_obj:
-                    best_obj, best_x = obj, xi
-                continue
-            expanded += 1
-            fl = np.floor(xlp[j])
-            for lo_j, hi_j in ((lbn[j], fl), (fl + 1, ubn[j])):
-                if lo_j > hi_j:
-                    continue
-                lb2, ub2 = lbn.copy(), ubn.copy()
-                lb2[j], ub2[j] = lo_j, hi_j
-                # child differs from parent in one variable's bounds
-                # only: warm-start the dual simplex from the parent basis
-                wave_specs.append(
-                    (lb2, ub2, node_warm if warm_nodes else None))
-        if limit and not wave_specs:
-            status = ILP_LIMIT
-            break
-        if wave_specs:
-            # the whole wave's children are bound-variants of one shared
-            # (c, A): one batched dispatch (sequential np loop at W=1)
-            ress = solve_lp_batch(
-                c, A, bl, bu, [s[1] for s in wave_specs],
-                [s[0] for s in wave_specs], max_iters=max_lp_iters,
-                warm_starts=[s[2] for s in wave_specs], budget=budget,
-                monitor=monitor, backend=batch_backend)
-            # vectorized _round_feasible over the wave: one (K, n)
-            # round/clip and one matmul per wave instead of per child —
-            # acceptance stays sequential (best_obj updates prune later
-            # children exactly as the per-child loop did)
-            live = [i for i, r in enumerate(ress)
-                    if r.status not in (INFEASIBLE, BUDGET)]
-            if live:
-                XI = np.clip(
-                    np.round(np.stack([ress[i].x for i in live])),
-                    np.stack([wave_specs[i][0] for i in live]),
-                    np.stack([wave_specs[i][1] for i in live]))
-                ACT = XI @ A.T
-                r_feas = (np.all(ACT >= bl - tol, axis=1)
-                          & np.all(ACT <= bu + tol, axis=1))
-                r_obj = XI @ c
-            ri = {k: j for j, k in enumerate(live)}
-            for i, ((lb2, ub2, _), res) in enumerate(zip(wave_specs,
-                                                         ress)):
-                lp_iters += res.iters
-                if res.status == INFEASIBLE:
-                    continue
-                if res.status == BUDGET:
-                    # child bound is unusable and the budget is gone: the
-                    # search is incomplete, never claim optimality
-                    status = ILP_LIMIT
-                    continue
-                if res.obj >= best_obj - 1e-9:
-                    continue
-                j = ri[i]
-                if r_feas[j] and r_obj[j] < best_obj:
-                    best_obj, best_x = float(r_obj[j]), XI[j]
-                heapq.heappush(heap, (res.obj, next(counter), lb2, ub2,
-                                      res.x, res.warm))
-        if limit:
-            status = ILP_LIMIT
-            break
+            if wave_specs:
+                # the whole wave's children are bound-variants of one shared
+                # (c, A): one batched dispatch (sequential np loop at W=1)
+                t_lp = time.perf_counter()
+                ress = solve_lp_batch(
+                    c, A, bl, bu, [s[1] for s in wave_specs],
+                    [s[0] for s in wave_specs], max_iters=max_lp_iters,
+                    warm_starts=[s[2] for s in wave_specs], budget=budget,
+                    monitor=monitor, backend=batch_backend)
+                if report is not None:
+                    report.ilp_node_lp_s += time.perf_counter() - t_lp
+                # vectorized _round_feasible over the wave: one (K, n)
+                # round/clip and one matmul per wave instead of per child —
+                # acceptance stays sequential (best_obj updates prune later
+                # children exactly as the per-child loop did)
+                live = [i for i, r in enumerate(ress)
+                        if r.status not in (INFEASIBLE, BUDGET)]
+                if live:
+                    XI = np.clip(
+                        np.round(np.stack([ress[i].x for i in live])),
+                        np.stack([wave_specs[i][0] for i in live]),
+                        np.stack([wave_specs[i][1] for i in live]))
+                    ACT = XI @ A.T
+                    r_feas = (np.all(ACT >= bl - tol, axis=1)
+                              & np.all(ACT <= bu + tol, axis=1))
+                    r_obj = XI @ c
+                ri = {k: j for j, k in enumerate(live)}
+                for i, ((lb2, ub2, _), res) in enumerate(zip(wave_specs,
+                                                             ress)):
+                    lp_iters += res.iters
+                    if res.status == INFEASIBLE:
+                        continue
+                    if res.status == BUDGET:
+                        # child bound is unusable and the budget is gone: the
+                        # search is incomplete, never claim optimality
+                        status = ILP_LIMIT
+                        continue
+                    if res.obj >= best_obj - 1e-9:
+                        continue
+                    j = ri[i]
+                    if r_feas[j] and r_obj[j] < best_obj:
+                        best_obj, best_x = float(r_obj[j]), XI[j]
+                    heapq.heappush(heap, (res.obj, next(counter), lb2, ub2,
+                                          res.x, res.warm))
+            if limit:
+                status = ILP_LIMIT
+                break
 
     if best_x is None:
         st = ILP_INFEASIBLE if status == ILP_OPTIMAL else ILP_LIMIT

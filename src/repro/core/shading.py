@@ -18,8 +18,6 @@ can only change iteration counts, never answers.
 """
 from __future__ import annotations
 
-import dataclasses
-import time
 from typing import Dict, Optional
 
 import numpy as np
@@ -32,6 +30,7 @@ from repro.core.lp_batch import solve_lp_batch
 from repro.core.neighbor import neighbor_sampling
 from repro.core.paql import PackageQuery
 from repro.core.relation import gather_column
+from repro.core.spans import span
 
 FALLBACK_SEED = 64   # LP-infeasible layer: seed with top-k by objective
 
@@ -287,32 +286,16 @@ def shading(hier: Hierarchy, l: int, alpha: int, S_l: np.ndarray,
     return S_next
 
 
-@dataclasses.dataclass
-class PSStats:
-    """Cascade-level observability for one progressive_shading call
-    (attached to the returned ``PackageResult.ps_stats``)."""
-    layer_sizes: list = dataclasses.field(default_factory=list)
-    lp_iters: int = 0
-    time_s: float = 0.0
-    # warm starts that silently fell cold: map_warm_basis re-maps that
-    # came back None, plus engine-side basis validations that rejected
-    # ("warm_start_rejected" LP notes)
-    warm_rejected: int = 0
-    cache: str = ""          # "" | "package" | "exact" | "contained"
-
-
-def _count_warm_rejects(lp_res, stats: PSStats, report) -> None:
+def _count_warm_rejects(lp_res, report) -> None:
     """Surface engine-side warm-start rejections (lp._warm_state notes)."""
     for note in getattr(lp_res, "notes", ()) or ():
         if "warm_start_rejected" in note:
-            stats.warm_rejected += 1
-            if report is not None:
-                report.warm_rejected += 1
+            report.warm_rejected += 1
 
 
 def _solve_from_cache(hier, query, table, hit, qcache, *, dr_q,
-                      ilp_kwargs, dr_aux, budget, report,
-                      stats: PSStats) -> Optional[PackageResult]:
+                      ilp_kwargs, dr_aux, budget,
+                      report) -> Optional[PackageResult]:
     """Serve a cache hit, or return None to fall back to the cold descent.
 
     Exact hits with a stored package take the validated fast path:
@@ -335,7 +318,7 @@ def _solve_from_cache(hier, query, table, hit, qcache, *, dr_q,
                     1e-6 * max(1.0, abs(entry.package_obj)):
                 if report is not None:
                     report.cache_pruned_lps += hier.L + 1
-                stats.cache = "package"
+                    report.cache_kind = "package"
                 return PackageResult(True, idx.copy(), mult.copy(), obj,
                                      entry.lp_bound,
                                      status="ok cached=package")
@@ -366,7 +349,7 @@ def _solve_from_cache(hier, query, table, hit, qcache, *, dr_q,
         return None
     if report is not None:
         report.cache_pruned_lps += hier.L
-    stats.cache = hit.kind
+        report.cache_kind = hit.kind
     res.status = f"ok cached={hit.kind}"
     return res
 
@@ -413,9 +396,8 @@ def progressive_shading(hier: Hierarchy, query: PackageQuery,
     Populate-after-solve — a clean, non-degraded cold solve stores its
     per-layer candidate sets, LP bases and final package.
     """
-    t0 = time.time()
     alpha = alpha or hier.alpha
-    stats = PSStats()
+    log = report.spans if report is not None else None
     fp = sig = hit = None
     owner = False
     if qcache is not None:
@@ -438,10 +420,8 @@ def progressive_shading(hier: Hierarchy, query: PackageQuery,
                 res = _solve_from_cache(hier, query, table, hit, qcache,
                                         dr_q=dr_q, ilp_kwargs=ilp_kwargs,
                                         dr_aux=dr_aux, budget=budget,
-                                        report=report, stats=stats)
+                                        report=report)
                 if res is not None:
-                    stats.time_s = time.time() - t0
-                    res.ps_stats = stats
                     return res
                 qcache.note_fallback()
                 if report is not None:
@@ -479,14 +459,16 @@ def progressive_shading(hier: Hierarchy, query: PackageQuery,
                 if state is not None and np.array_equal(
                         np.asarray(state[0]), np.asarray(S)):
                     warm = WarmStart(state[1].copy(), state[2].copy())
-            S_next, lp_res, S_used, support = shading(
-                hier, l, alpha, S, query, layer_solver=layer_solver,
-                sampler=sampler, rng=rng, warm_start=warm,
-                return_state=True, lp_solver=lp_solver, budget=budget,
-                report=report, widen=widen, ladder=ladder, skip_lp=skip)
+            with span(log, "shade"):
+                S_next, lp_res, S_used, support = shading(
+                    hier, l, alpha, S, query, layer_solver=layer_solver,
+                    sampler=sampler, rng=rng, warm_start=warm,
+                    return_state=True, lp_solver=lp_solver, budget=budget,
+                    report=report, widen=widen, ladder=ladder,
+                    skip_lp=skip)
             if lp_res is not None:
-                stats.lp_iters += int(lp_res.iters)
-                _count_warm_rejects(lp_res, stats, report)
+                if report is not None:
+                    _count_warm_rejects(lp_res, report)
                 if lp_res.status == OPTIMAL:
                     art_layers[l] = (S_used, lp_res.basis, lp_res.at_upper,
                                      lp_res.obj)
@@ -496,7 +478,6 @@ def progressive_shading(hier: Hierarchy, query: PackageQuery,
                 if warm_starts else None
             if warm_starts and lp_res is not None \
                     and lp_res.status == OPTIMAL and warm is None:
-                stats.warm_rejected += 1
                 if report is not None:
                     report.warm_rejected += 1
                     report.note(f"warm_map_rejected: layer {l}")
@@ -542,9 +523,6 @@ def progressive_shading(hier: Hierarchy, query: PackageQuery,
                          lp_bound=res.lp_obj,
                          package=(res.idx, res.mult, res.obj))
         res.status += f" layers={sizes}"
-        stats.layer_sizes = sizes
-        stats.time_s = time.time() - t0
-        res.ps_stats = stats
         return res
     finally:
         # Release the populate claim whether or not the solve stored

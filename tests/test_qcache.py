@@ -100,7 +100,7 @@ def test_exact_hit_package_parity_and_counters(dataset):
     assert r2.report.cache_hits == 1 and r2.report.cache_pruned_lps > 0
     assert r1.report.cache_misses == 1
     assert "cache=" in r2.report.summary()
-    assert r2.ps_stats is not None and r2.ps_stats.cache == "package"
+    assert r2.report.cache_kind == "package"
 
 
 def test_artifact_only_mode_parity(dataset):
@@ -272,7 +272,6 @@ def test_warm_rejected_surfaced(dataset, monkeypatch):
     eng = _engine(table)
     res = eng.solve(q, ilp_kwargs=ILP_KW)
     assert res.feasible
-    assert res.ps_stats.warm_rejected > 0
     assert res.report.warm_rejected > 0
     assert "warm_rejected" in res.report.summary()
     assert any("warm_map_rejected" in n for n in res.report.notes)
