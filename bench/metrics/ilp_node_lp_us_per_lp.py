@@ -1,0 +1,12 @@
+"""Microseconds a B&B node LP takes: SolveReport.ilp_node_lp_s over
+ilp_node_lps (carried on pq.solve), summed over the queries of the
+traced window."""
+from bench.lib import program_spans as ps
+
+
+def read(rec):
+    qs = [q["stats"] for q in ps.window_queries(rec)
+          if "ilp_node_lps" in q["stats"]]
+    lps = sum(s["ilp_node_lps"] for s in qs)
+    return 1e6 * sum(s.get("ilp_node_lp_s", 0.0) for s in qs) / lps \
+        if lps else None

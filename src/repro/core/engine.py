@@ -151,9 +151,12 @@ class PackageQueryEngine:
                 res = PackageResult(False, np.zeros(0, np.int64),
                                     np.zeros(0), 0.0, 0.0, status="error")
             # the counters ride on the trace's pq.solve event
-            ann.set_metadata(ilp_lp_pivots=report.ilp_lp_pivots,
-                             ilp_node_lp_s=report.ilp_node_lp_s,
-                             ilp_capped=report.ilp_capped)
+            ann.set_metadata(
+                ilp_lp_pivots=report.ilp_lp_pivots,
+                ilp_node_lp_s=report.ilp_node_lp_s,
+                ilp_node_lps=report.ilp_node_lps,
+                ilp_node_lps_carried=report.ilp_node_lps_carried,
+                ilp_capped=report.ilp_capped)
         report.fault_retries = io_retry_count() - io0
         res.report = report.finalize(res.feasible)
         res.status += f" t={time.time() - t0:.3f}s"

@@ -217,6 +217,9 @@ class SolveReport:
     ilp_nodes: int = 0
     ilp_lp_pivots: int = 0    # B&B root and node LP pivots (lp_iters)
     ilp_node_lp_s: float = 0.0  # seconds in B&B's node LP solves
+    ilp_node_lps: int = 0     # node LPs solved in B&B's search
+    ilp_node_lps_carried: int = 0  # of them, resumed from the parent's
+                                   # factorization
     ilp_capped: int = 0       # sub-ILPs stopped at a node/time/budget cap
     fault_retries: int = 0
     wall_s: float = 0.0

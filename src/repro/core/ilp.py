@@ -6,11 +6,14 @@ cheap; best-first search with a most-fractional branching rule and a
 round-and-check incumbent heuristic handles the Dual Reducer sub-ILPs
 (q ≈ 500 variables) comfortably.
 
-Every node LP differs from its parent's only in one variable's bounds, so
-node re-solves (and the diving / feasibility-pump LPs) are warm-started
-from the parent basis — the textbook dual-simplex case (core.lp); the
-root accepts an external ``warm_start`` (Dual Reducer passes lp1's basis
-re-mapped onto the sub-ILP columns).
+Every node LP differs from its parent's only in one variable's bounds —
+the textbook dual-simplex warm start (core.lp).  In the one-node-at-a-time
+search a node resumes the dual simplex from its parent's final
+factorization, over a scaled standard form built once per ``solve_ilp``
+(``lp.solve_lp_resume``); the diving / feasibility-pump LPs and batched
+waves are warm-started from the parent basis.  The root accepts an
+external ``warm_start`` (Dual Reducer passes lp1's basis re-mapped onto
+the sub-ILP columns).
 
 Minimisation form throughout (PackageQuery.matrices already negates
 MAXIMIZE objectives).
@@ -25,7 +28,8 @@ from typing import Optional
 
 import numpy as np
 
-from repro.core.lp import solve_lp_np, BUDGET, OPTIMAL, INFEASIBLE
+from repro.core.lp import (BUDGET, INFEASIBLE, OPTIMAL, prepare_lp,
+                           solve_lp_np, solve_lp_resume)
 from repro.core.lp_batch import solve_lp_batch
 from repro.core.spans import span
 
@@ -273,6 +277,11 @@ def solve_ilp(c, A, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
               batch_backend: Optional[str] = None,
               report=None) -> ILPResult:
     """warm_nodes=False disables node-LP warm starting (benchmark knob).
+    Otherwise each node LP starts from its parent's final basis: at
+    ``wave_width=1`` on the numpy engine it resumes from the parent's
+    final factorization (``lp.solve_lp_resume``) over a standard form
+    built once, after the root LP — the same pivots and answer as a warm
+    start, without rebuilding the form or refactorizing.
 
     ``budget=`` (a ``guard.SolveBudget``) clamps the node/time limits to
     what remains, charges every explored node against the shared node
@@ -283,17 +292,18 @@ def solve_ilp(c, A, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
     ``wave_width=W`` explores the frontier in waves: the W best-bound
     nodes are popped together and their child LPs — pure bound-variants
     of one shared ``(c, A)``, each warm-started from its parent — are
-    solved as ONE ``solve_lp_batch`` dispatch.  ``W=1`` keeps today's
-    one-node-at-a-time loop bit-identical (the batch engine degrades to
-    the same sequential ``solve_lp_np`` calls); larger W trades a few
-    extra node expansions (children of wave-mates can't prune each
-    other before solving) for one dispatch per wave.  ``batch_backend``
-    overrides the engine choice (default: ``"np"`` for W=1, ``"auto"``
-    otherwise).
+    solved as ONE ``solve_lp_batch`` dispatch.  ``W=1`` is the
+    one-node-at-a-time loop, its children solved one by one; larger W
+    trades a few extra node expansions (children of wave-mates can't
+    prune each other before solving) for one dispatch per wave.
+    ``batch_backend`` overrides the engine choice (default: ``"np"`` for
+    W=1, ``"auto"`` otherwise).
 
     ``report=`` (a ``guard.SolveReport``) records the spans
     ``ilp.incumbent`` (root LP and heuristics) and ``ilp.search`` (the
-    node loop) and adds the node LPs' seconds to ``ilp_node_lp_s``.
+    node loop), adds the node LPs' seconds to ``ilp_node_lp_s``, their
+    number to ``ilp_node_lps`` and of those the ones resumed from their
+    parent's factorization to ``ilp_node_lps_carried``.
     """
     c = np.asarray(c, np.float64)
     A = np.atleast_2d(np.asarray(A, np.float64))
@@ -339,18 +349,21 @@ def solve_ilp(c, A, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
 
     heap = []
     counter = itertools.count()
-    heapq.heappush(heap, (root.obj, next(counter), lb0, ub0, root.x,
-                          root.warm))
+    heapq.heappush(heap, (root.obj, next(counter), lb0, ub0, root.x, root))
     nodes = 0
     t0 = time.time()
     status = ILP_OPTIMAL
     wave_width = max(1, int(wave_width))
     if batch_backend is None:
         batch_backend = "np" if wave_width == 1 else "auto"
+    # one node at a time on the numpy engine: children resume from their
+    # parent's factorization over one shared form
+    form = prepare_lp(c, A, bl, bu) if (
+        warm_nodes and wave_width == 1 and batch_backend == "np") else None
     with span(log, "ilp.search"):
         while heap:
             # ---- gather one frontier wave: up to W best-bound expansions ----
-            wave_specs = []       # (lb2, ub2, parent warm-start)
+            wave_specs = []       # (lb2, ub2, parent LPResult or None)
             expanded = 0
             limit = False
             while heap and expanded < wave_width:
@@ -358,7 +371,7 @@ def solve_ilp(c, A, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
                         (budget is not None and budget.exhausted()):
                     limit = True
                     break
-                bound, _, lbn, ubn, xlp, node_warm = heapq.heappop(heap)
+                bound, _, lbn, ubn, xlp, parent = heapq.heappop(heap)
                 if bound >= best_obj - 1e-9:
                     continue
                 nodes += 1
@@ -381,23 +394,43 @@ def solve_ilp(c, A, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
                     lb2, ub2 = lbn.copy(), ubn.copy()
                     lb2[j], ub2[j] = lo_j, hi_j
                     # child differs from parent in one variable's bounds
-                    # only: warm-start the dual simplex from the parent basis
+                    # only: start the dual simplex from the parent's basis
                     wave_specs.append(
-                        (lb2, ub2, node_warm if warm_nodes else None))
+                        (lb2, ub2, parent if warm_nodes else None))
             if limit and not wave_specs:
                 status = ILP_LIMIT
                 break
             if wave_specs:
-                # the whole wave's children are bound-variants of one shared
-                # (c, A): one batched dispatch (sequential np loop at W=1)
                 t_lp = time.perf_counter()
-                ress = solve_lp_batch(
-                    c, A, bl, bu, [s[1] for s in wave_specs],
-                    [s[0] for s in wave_specs], max_iters=max_lp_iters,
-                    warm_starts=[s[2] for s in wave_specs], budget=budget,
-                    monitor=monitor, backend=batch_backend)
+                carried = 0
+                if form is not None:
+                    ress = []
+                    for lb2, ub2, parent in wave_specs:
+                        res = solve_lp_resume(
+                            form, lb2, ub2, parent.factors,
+                            max_iters=max_lp_iters, budget=budget,
+                            monitor=monitor)
+                        if res is None:   # factors not usable: warm start
+                            res = solve_lp_np(
+                                c, A, bl, bu, ub2, lb=lb2,
+                                max_iters=max_lp_iters, warm_start=parent,
+                                budget=budget, monitor=monitor)
+                        else:
+                            carried += 1
+                        ress.append(res)
+                else:
+                    # the whole wave's children are bound-variants of one
+                    # shared (c, A): one batched dispatch
+                    ress = solve_lp_batch(
+                        c, A, bl, bu, [s[1] for s in wave_specs],
+                        [s[0] for s in wave_specs], max_iters=max_lp_iters,
+                        warm_starts=[s[2] for s in wave_specs],
+                        budget=budget, monitor=monitor,
+                        backend=batch_backend)
                 if report is not None:
                     report.ilp_node_lp_s += time.perf_counter() - t_lp
+                    report.ilp_node_lps += len(ress)
+                    report.ilp_node_lps_carried += carried
                 # vectorized _round_feasible over the wave: one (K, n)
                 # round/clip and one matmul per wave instead of per child —
                 # acceptance stays sequential (best_obj updates prune later
@@ -430,7 +463,7 @@ def solve_ilp(c, A, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
                     if r_feas[j] and r_obj[j] < best_obj:
                         best_obj, best_x = float(r_obj[j]), XI[j]
                     heapq.heappush(heap, (res.obj, next(counter), lb2, ub2,
-                                          res.x, res.warm))
+                                          res.x, res))
             if limit:
                 status = ILP_LIMIT
                 break

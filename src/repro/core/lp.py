@@ -51,6 +51,16 @@ Warm-start contract:
   falls back to the cold all-slack start when invalid — a warm start can
   only change the iteration count, never the answer.
 
+  ``solve_lp_resume(form, lb, ub, factors)`` is the warm start without
+  its set-up, for LPs that differ only in x̃'s bounds (branch & bound's
+  nodes): ``form = prepare_lp(c, A_t, bl, bu)`` is built once, and
+  ``factors`` is another variant's ``LPResult.factors`` — its final basis
+  with the fresh Binv, y and d in the scaled space.  Only the bound
+  placement is redone; the pivots and the answer are those of
+  ``solve_lp_np(..., warm_start=(basis, at_upper))``.  The numpy twin
+  answers from the factors of its last refactorization when no pivot
+  came after it, and factorizes afresh otherwise.
+
 Two twin implementations with identical pivot rules:
   solve_lp_np  — numpy, used by branch & bound re-solves and as the oracle,
   solve_lp     — jax.lax.while_loop under jit (f64), used by the benchmarks
@@ -83,6 +93,10 @@ class LPResult:
     y: np.ndarray            # duals (m,)
     notes: Tuple[str, ...] = ()   # solver events (warm rejection, stalls,
                                   # budget truncation) for the SolveReport
+    # the numpy twin's final fresh factors, for a bound-variant to resume
+    # from (``solve_lp_resume``); None from the other twins
+    factors: Optional["Factors"] = dataclasses.field(default=None,
+                                                     repr=False)
 
     @property
     def feasible(self) -> bool:
@@ -99,6 +113,37 @@ class WarmStart:
     """Starting basis for the dual simplex (see module docstring)."""
     basis: np.ndarray
     at_upper: Optional[np.ndarray] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class Factors:
+    """A basis with its fresh factors in the scaled standard form
+    (``prepare_lp``).  Read-only: a solve that resumes from it copies
+    what it updates, so siblings can share one parent's."""
+    basis: np.ndarray
+    at_upper: np.ndarray
+    Binv: np.ndarray         # inverse of A[:, basis]
+    y: np.ndarray            # duals, scaled units
+    d: np.ndarray            # reduced costs cf - Aᵀy, zero on the basis
+
+
+@dataclasses.dataclass(frozen=True)
+class LPForm:
+    """The row-scaled standard form of ``(c, A_t, bl, bu)``, shared by
+    the LPs that differ from it only in x̃'s bounds."""
+    cf: np.ndarray
+    A: np.ndarray
+    bl: np.ndarray           # scaled row bounds: the slacks' bounds
+    bu: np.ndarray
+    scale: np.ndarray
+
+    def box(self, lb, ub):
+        """Bounds (l, u) over [x̃ | s] for lb <= x̃ <= ub (lb None: 0)."""
+        n = self.A.shape[1] - len(self.bl)
+        l = np.concatenate([np.zeros(n), self.bl])
+        if lb is not None:
+            l[:n] = lb
+        return l, np.concatenate([np.asarray(ub, np.float64), self.bu])
 
 
 def _unpack_warm(warm_start):
@@ -144,11 +189,9 @@ def _warm_state(cf, A, l, u, warm_basis, at_upper_hint, tol):
     ((basis, in_basis, at_upper, Binv, y, d), None) or (None, reason).
 
     Dual feasibility is restored for free by placing every nonbasic column
-    at the bound matching the sign of its reduced cost; the ``at_upper``
-    hint only decides columns whose reduced cost is ~zero (degenerate),
-    which preserves the warm solve's primal point.  The factors computed
-    for validation (Binv, y, d) are returned so the solver can seed its
-    state without refactorizing again.
+    at the bound matching the sign of its reduced cost (``_place``).  The
+    factors computed for validation (Binv, y, d) are returned so the
+    solver can seed its state without refactorizing again.
 
     A rejected basis is never an error — the caller falls back to the
     cold all-slack start — but it is no longer *silent*: the reason is
@@ -165,30 +208,43 @@ def _warm_state(cf, A, l, u, warm_basis, at_upper_hint, tol):
         Binv = np.linalg.inv(A[:, basis])
     except np.linalg.LinAlgError:
         return None, "singular basis"
-    if not np.all(np.isfinite(Binv)) or np.abs(Binv).max() > 1e12:
-        return None, "ill-conditioned basis"
-    in_basis = np.zeros(N, bool)
-    in_basis[basis] = True
     y = Binv.T @ cf[basis]
     d = cf - A.T @ y
     d[basis] = 0.0
+    placed, why = _place(basis, Binv, d, l, u, at_upper_hint, tol)
+    if placed is None:
+        return None, why
+    return (basis.copy(), *placed, Binv, y, d), None
+
+
+def _place(basis, Binv, d, l, u, at_upper_hint, tol):
+    """Bound placement of a factored basis: ((in_basis, at_upper), None)
+    or (None, reason).  Each nonbasic column sits at the bound its
+    reduced cost's sign asks for; the ``at_upper`` hint decides only
+    columns whose reduced cost is ~zero (degenerate), which preserves the
+    warm solve's primal point."""
+    if not np.all(np.isfinite(Binv)) or np.abs(Binv).max() > 1e12:
+        return None, "ill-conditioned basis"
+    N = len(d)
+    in_basis = np.zeros(N, bool)
+    in_basis[basis] = True
     hint = np.zeros(N, bool)
     if at_upper_hint is not None:
         h = np.asarray(at_upper_hint, bool).ravel()
         if h.shape == (N,):
-            hint = h.copy()
-    at_upper = np.where(d < -tol, True, np.where(d > tol, False, hint))
-    at_upper |= np.isinf(l)            # -inf lower: must sit at upper
-    at_upper &= ~np.isinf(u)           # +inf upper: must sit at lower
+            hint = h
+    neg, pos = d < -tol, d > tol
+    l_inf, u_inf = np.isinf(l), np.isinf(u)
+    at_upper = neg | (hint & ~pos)
+    at_upper |= l_inf                  # -inf lower: must sit at upper
+    at_upper &= ~u_inf                 # +inf upper: must sit at lower
     # a nonbasic column whose reduced-cost sign demands an infinite bound
     # cannot be made dual-feasible by bound placement -> reject the basis
-    bad = (~in_basis) & (((d < -tol) & np.isinf(u))
-                         | ((d > tol) & np.isinf(l))
-                         | (np.isinf(l) & np.isinf(u)))
-    if np.any(bad):
+    if np.any(~in_basis & ((neg & u_inf) | (pos & l_inf)
+                           | (l_inf & u_inf))):
         return None, "dual-infeasible column pinned at an infinite bound"
     at_upper[in_basis] = False
-    return (basis.copy(), in_basis, at_upper, Binv, y, d), None
+    return (in_basis, at_upper), None
 
 
 def fill_warm_basis(new_basis, n_new: int, m: int):
@@ -209,6 +265,19 @@ def fill_warm_basis(new_basis, n_new: int, m: int):
     return np.asarray(out, np.int64)
 
 
+def prepare_lp(c, A_t, bl, bu) -> LPForm:
+    """Row scaling and standard form of ``(c, A_t, bl, bu)``, built once
+    for every bound-variant of the LP (``solve_lp_resume``)."""
+    c = np.asarray(c, np.float64)
+    A_t = np.atleast_2d(np.asarray(A_t, np.float64))
+    scale = row_scaling(A_t)
+    A_t = A_t * scale[:, None]
+    bl = np.asarray(bl, np.float64) * scale
+    bu = np.asarray(bu, np.float64) * scale
+    cf, A, _, _ = standard_form(c, A_t, bl, bu, np.zeros(A_t.shape[1]))
+    return LPForm(cf, A, bl, bu, scale)
+
+
 def _prep(c, A_t, bl, bu, ub, lb, warm_start, tol=1e-7):
     """Shared solver setup: scale, standard form, warm-basis validation.
 
@@ -217,17 +286,11 @@ def _prep(c, A_t, bl, bu, ub, lb, warm_start, tol=1e-7):
     (basis, in_basis, at_upper, Binv, y, d) or None for a cold start, and
     wnote records why a requested warm basis was rejected (else None).
     """
-    c = np.asarray(c, np.float64)
-    A_t = np.atleast_2d(np.asarray(A_t, np.float64))
-    m, n = A_t.shape
-    scale = row_scaling(A_t)
-    A_t = A_t * scale[:, None]
-    bl = np.asarray(bl, np.float64) * scale
-    bu = np.asarray(bu, np.float64) * scale
-    cf, A, l, u = standard_form(c, A_t, bl, bu, np.asarray(ub, np.float64))
-    if lb is not None:
-        l[:n] = lb
-    N = n + m
+    form = prepare_lp(c, A_t, bl, bu)
+    cf, A, scale = form.cf, form.A, form.scale
+    l, u = form.box(lb, ub)
+    m, N = A.shape
+    n = N - m
     if np.any(l > u + tol):
         return None, scale, m, n, None
     wb, wh = _unpack_warm(warm_start)
@@ -264,36 +327,79 @@ def solve_lp_np(c, A_t, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
     """
     arrs, scale, m, n, start = _prep(c, A_t, bl, bu, ub, lb, warm_start,
                                      tol)
-    N = n + m
     if arrs is None:
-        return LPResult(INFEASIBLE, np.zeros(n), 0.0, 0,
-                        np.arange(n, N), np.zeros(N, bool), np.zeros(m))
-    cf, A, l, u = arrs
+        return LPResult(INFEASIBLE, np.zeros(n), 0.0, 0, np.arange(n, n + m),
+                        np.zeros(n + m, bool), np.zeros(m))
     basis0, at_upper0, winit, wnote = start
-    notes = [] if wnote is None else [wnote]
+    return _dual_simplex(*arrs, scale, basis0.copy(), at_upper0.copy(),
+                         None if winit is None else winit[3:],
+                         [] if wnote is None else [wnote],
+                         max_iters=max_iters, tol=tol,
+                         refactor_every=refactor_every, budget=budget,
+                         monitor=monitor)
+
+
+def solve_lp_resume(form: LPForm, lb, ub, start: Factors, *,
+                    max_iters: int = 5000, tol: float = 1e-7,
+                    refactor_every: int = REFACTOR_EVERY,
+                    budget: Optional[SolveBudget] = None,
+                    monitor: Optional[NumericalMonitor] = None
+                    ) -> Optional[LPResult]:
+    """The LP of ``form`` with lb <= x̃ <= ub, resumed from ``start``: the
+    ``factors`` of a solve of the same form under other bounds.
+
+    The same pivots and answer as ``solve_lp_np(...,
+    warm_start=(start.basis, start.at_upper))``, without rebuilding the
+    form or factorizing the basis again.  None where the box is empty or
+    ``start`` cannot be placed in it; ``solve_lp_np`` handles both.
+    """
+    l, u = form.box(lb, ub)
+    if np.any(l > u + tol):
+        return None
+    placed, _ = _place(start.basis, start.Binv, start.d, l, u,
+                       start.at_upper, tol)
+    if placed is None:
+        return None
+    return _dual_simplex(form.cf, form.A, l, u, form.scale,
+                         start.basis.copy(), placed[1],
+                         (start.Binv.copy(), start.y.copy(),
+                          start.d.copy()), [],
+                         max_iters=max_iters, tol=tol,
+                         refactor_every=refactor_every, budget=budget,
+                         monitor=monitor)
+
+
+def _dual_simplex(cf, A, l, u, scale, basis, at_upper, factors, notes, *,
+                  max_iters, tol, refactor_every, budget, monitor
+                  ) -> LPResult:
+    """The numpy twin's pivot loop over a scaled standard form from
+    ``basis`` / ``at_upper`` and, where given, that basis's fresh
+    ``factors`` (Binv, y, d); all five are the loop's own, updated in
+    place.  Without factors it factorizes first."""
+    m, N = A.shape
+    n = N - m
     mon = monitor if monitor is not None else NumericalMonitor()
     if budget is not None:
         budget.start()
-    basis = basis0.copy()
-    at_upper = at_upper0.copy()
     in_basis = np.zeros(N, bool)
     in_basis[basis] = True
-    if winit is not None:
-        # reuse the factors computed during warm-basis validation
-        _, _, _, Binv, y, d = winit
+    if factors is not None:
+        Binv, y, d = factors
         xN = np.where(in_basis, 0.0, np.where(at_upper, u, l))
         xN[basis] = 0.0
         xB = -Binv @ (A @ xN)
         since = 0
+        fresh = Binv
     else:
         Binv = np.eye(m)
         xB = np.zeros(m)
         y = np.zeros(m)
         d = cf.copy()
         since = refactor_every      # force a full factorization first
+        fresh = None
 
     def refresh():
-        nonlocal Binv, xB, y, d, since
+        nonlocal Binv, xB, y, d, since, fresh
         Binv = np.linalg.inv(A[:, basis])
         xN = np.where(in_basis, 0.0, np.where(at_upper, u, l))
         xN[basis] = 0.0
@@ -302,7 +408,9 @@ def solve_lp_np(c, A_t, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
         d = cf - A.T @ y
         d[basis] = 0.0
         since = 0
+        fresh = Binv
 
+    width = u - l
     status = ITER_LIMIT
     iters = 0
     stall = 0
@@ -352,37 +460,33 @@ def solve_lp_np(c, A_t, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
         alpha = rho @ A           # pricing: the single O(mn) sweep, ∥ over n
 
         sa = s * alpha
-        elig = (~in_basis) & (
-            ((~at_upper) & (sa > tol)) | (at_upper & (sa < -tol)))
-        if not np.any(elig):
+        # entering candidates: nonbasic columns that can leave their bound
+        # in the direction that reduces the leaving row's infeasibility
+        cand = np.flatnonzero(~in_basis & np.where(at_upper, sa < -tol,
+                                                   sa > tol))
+        if not len(cand):
             if since > 0:         # could be drift: retry on fresh factors
                 refresh()
                 continue
             status = INFEASIBLE
             break
-        ratio = np.where(elig, d / np.where(np.abs(sa) > tol, sa, 1.0), np.inf)
-        ratio = np.where(elig, np.maximum(ratio, 0.0), np.inf)
+        ratio = np.maximum(d[cand] / sa[cand], 0.0)   # |sa| > tol on cand
 
         if bland:
             # Bland's rule: smallest-index min-ratio column, no bound
             # flips — finite (anti-cycling) at the cost of progress/pivot
             rmin = float(np.min(ratio))
-            q = int(np.argmax(elig & (ratio <= rmin + 1e-12)))
+            q = int(cand[np.argmax(ratio <= rmin + 1e-12)])
             flips = np.empty(0, np.int64)
             mon.bland_pivots += 1
         else:
             # ---- BFRT: walk breakpoints in ratio order, flipping bounds
             # while the remaining infeasibility budget allows (App. C.3).
-            width = u - l
-            flip_cost = np.full(N, np.inf)
-            flip_cost[elig] = np.abs(alpha[elig]) * width[elig]
-            order = np.argsort(ratio, kind="stable")
-            k_elig = int(np.sum(elig))
-            cand = order[:k_elig]
-            csum = np.cumsum(flip_cost[cand])
+            cand = cand[np.argsort(ratio, kind="stable")]
+            csum = np.cumsum(np.abs(alpha[cand]) * width[cand])
             flip_budget = abs(delta)
             cross = int(np.searchsorted(csum, flip_budget - 1e-12))
-            if cross >= k_elig:
+            if cross >= len(cand):
                 if since > 0:     # dual unbounded on stale factors: re-check
                     refresh()
                     continue
@@ -447,18 +551,21 @@ def solve_lp_np(c, A_t, bl, bu, ub, *, lb: Optional[np.ndarray] = None,
 
     if budget is not None:
         budget.charge_pivots(iters)
-    # final answer always from a fresh factorization
-    Binv = np.linalg.inv(A[:, basis])
+    # the answer always comes from a fresh factorization: the last one's
+    # where no pivot (nor an injected perturbation of Binv) came since
+    if since or Binv is not fresh:
+        refresh()
     xN = np.where(in_basis, 0.0, np.where(at_upper, u, l))
     xN[basis] = 0.0
-    xB = -Binv @ (A @ xN)
     x = xN.copy()
     x[basis] = xB
-    y = Binv.T @ cf[basis]
     obj_min = float(cf @ np.where(np.isfinite(x), x, 0.0))
-    return LPResult(status, x[:n], obj_min, iters, basis.copy(),
-                    at_upper.copy(), y * scale,   # duals in original units
-                    notes=tuple(notes))
+    basis = basis.copy()
+    at_upper = at_upper.copy()
+    return LPResult(status, x[:n], obj_min, iters, basis, at_upper,
+                    y * scale,   # duals in original units
+                    notes=tuple(notes),
+                    factors=Factors(basis, at_upper, Binv, y, d))
 
 
 # ----------------------------------------------------------------- JAX twin

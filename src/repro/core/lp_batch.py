@@ -477,7 +477,7 @@ def solve_lp_batch(c, A_t, bl, bu, ub_batch, lb_batch=None, *,
         _STATS["instances"] += K
     if backend == "np" or (backend == "auto" and K <= _AUTO_NP_MAX):
         # sequential fallback: per-call budget charging, identical to the
-        # existing caller loops (this is what makes W=1 bit-compatible)
+        # existing caller loops
         with _STATS_LOCK:
             _STATS["np_fallbacks"] += 1
         return [solve_lp_np(c, A_t, bl, bu, ub_arr[k], lb=lb_arr[k],

@@ -1,10 +1,15 @@
 """ILP branch & bound + heuristics vs exhaustive enumeration."""
+import math
+from statistics import NormalDist
+
 import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis", reason="hypothesis not installed")
 from hypothesis import given, settings, strategies as st
 
+import repro.core.ilp as ilp_mod
+from repro.core.guard import SolveReport
 from repro.core.ilp import (ILP_OPTIMAL, brute_force_ilp, solve_ilp,
                             _swap_search)
 
@@ -72,3 +77,67 @@ def test_swap_search_repairs_tight_window():
     assert x is not None
     act = A @ x
     assert np.all(act >= bl - 1e-6) and np.all(act <= bu + 1e-6)
+
+
+def _q2_ilp(seed, hardness, n=500):
+    """A Dual Reducer sub-ILP of Q2_TPCH's shape: maximise price over n
+    lineitem-like tuples, each used at most once, COUNT in [15, 45],
+    quantity >=, discount <=, tax between, the bounds drawn at
+    ``hardness`` as the paper's Sec. 4.1 draws them."""
+    rng = np.random.default_rng(seed)
+    qty = rng.integers(1, 51, n).astype(float)
+    price = qty * rng.uniform(900.0, 2100.0, n)
+    disc = price * rng.integers(0, 11, n) / 100
+    tax = price * rng.integers(0, 9, n) / 100
+    E, p = 30.0, 10.0 ** (-hardness / 3)
+    z = NormalDist().inv_cdf
+
+    def at(v, k):
+        return E * v.mean() + k * math.sqrt(E) * v.std()
+
+    zb = z(0.5 * (1 + p))
+    A = np.stack([np.ones(n), qty, disc, tax])
+    bl = np.array([15.0, at(qty, z(1 - p)), -np.inf, at(tax, -zb)])
+    bu = np.array([45.0, np.inf, at(disc, z(p)), at(tax, zb)])
+    return -price, A, bl, bu, np.ones(n)
+
+
+CASES = ([("q2", s, h) for s, h in ((0, 1.0), (0, 7.0), (4, 7.0),
+                                    (1, 3.0), (2, 5.0))]
+         + [("small", s, None) for s in range(12)])
+
+
+@pytest.mark.parametrize("kind,seed,hardness", CASES,
+                         ids=[f"{k}-{s}-{h}" for k, s, h in CASES])
+def test_carried_factorization_matches_warm_start(kind, seed, hardness,
+                                                  monkeypatch):
+    """Node LPs resumed from the parent's factorization search the same
+    tree as node LPs warm-started from the parent's basis: the same
+    status, nodes, pivots, objective and package."""
+    ilp = _q2_ilp(seed, hardness) if kind == "q2" else _random_ilp(seed)
+    runs = []
+    for carry in (True, False):
+        if not carry:   # no factors carried: every node goes the warm way
+            monkeypatch.setattr(ilp_mod, "solve_lp_resume",
+                                lambda *a, **k: None)
+        rep = SolveReport()
+        runs.append((solve_ilp(*ilp, max_nodes=300, report=rep), rep))
+    (r1, rep1), (r0, rep0) = runs
+    assert (r1.status, r1.nodes, r1.lp_iters) == \
+        (r0.status, r0.nodes, r0.lp_iters)
+    assert r1.obj == r0.obj and np.array_equal(r1.x, r0.x)
+    assert rep1.ilp_node_lps == rep0.ilp_node_lps
+    assert rep1.ilp_node_lps_carried == rep1.ilp_node_lps
+    assert rep0.ilp_node_lps_carried == 0
+    if kind == "q2":
+        assert rep1.ilp_node_lps > 100
+
+
+@pytest.mark.parametrize("kw", [dict(wave_width=4), dict(warm_nodes=False)],
+                         ids=["wave4", "cold-nodes"])
+def test_only_the_node_loop_carries(kw):
+    """Batched waves and cold node LPs carry no factorization."""
+    rep = SolveReport()
+    r = solve_ilp(*_q2_ilp(0, 7.0), max_nodes=100, report=rep, **kw)
+    assert r.feasible
+    assert rep.ilp_node_lps > 0 and rep.ilp_node_lps_carried == 0

@@ -11,8 +11,8 @@ import importlib.util
 import numpy as np
 import pytest
 
-from repro.core.lp import (OPTIMAL, WarmStart, solve_lp, solve_lp_np,
-                           verify_optimality)
+from repro.core.lp import (OPTIMAL, WarmStart, prepare_lp, solve_lp,
+                           solve_lp_np, solve_lp_resume, verify_optimality)
 from repro.core.lp_kernel import solve_lp_kernel
 
 HAS_HYPOTHESIS = importlib.util.find_spec("hypothesis") is not None
@@ -216,6 +216,116 @@ def test_progressive_shading_warm_equals_cold():
     assert res_w.feasible == res_c.feasible
     if res_w.feasible:
         assert res_w.obj == pytest.approx(res_c.obj, rel=0.05, abs=0.5)
+
+
+def _child_bounds(res, lb, ub, lo_side):
+    """A B&B child of ``res``: the bounds of its most fractional (hence
+    basic) variable cut at the floor, ``lo_side`` keeping the lower
+    part; None where ``res.x`` is integral."""
+    frac = np.abs(res.x - np.round(res.x))
+    j = int(np.argmax(frac))
+    if frac[j] < 1e-6:
+        return None
+    lb2, ub2 = np.array(lb, np.float64), np.array(ub, np.float64)
+    if lo_side:
+        ub2[j] = np.floor(res.x[j])
+    else:
+        lb2[j] = np.floor(res.x[j]) + 1
+    return lb2, ub2
+
+
+def _same_result(a, b):
+    assert a.status == b.status
+    assert a.iters == b.iters
+    assert np.array_equal(a.x, b.x)
+    assert a.obj == b.obj
+    assert np.array_equal(a.basis, b.basis)
+    assert np.array_equal(a.at_upper, b.at_upper)
+    assert np.array_equal(a.y, b.y)
+
+
+def _branching_lps(seeds):
+    """(lp, optimum) of the seeds whose LP optimum is fractional."""
+    for seed in seeds:
+        lp = _random_lp(seed, one_sided=False)
+        res = solve_lp_np(*lp)
+        if res.status == OPTIMAL and _child_bounds(
+                res, np.zeros(len(res.x)), lp[4], True) is not None:
+            yield lp, res
+
+
+@pytest.mark.parametrize("block", range(5))
+@pytest.mark.parametrize("lo_side", [True, False], ids=["floor", "ceil"])
+def test_resume_matches_warm_start(block, lo_side):
+    """A bound-variant resumed from its parent's carried factors gives
+    the LPResult of the same LP warm-started from the parent's basis,
+    and so does its own child resumed from it."""
+    compared = 0
+    for (c, A, bl, bu, ub), parent in _branching_lps(
+            range(8 * block, 8 * block + 8)):
+        form = prepare_lp(c, A, bl, bu)
+        lb, res = np.zeros(len(ub)), parent
+        for _ in range(2):
+            lb, ub = _child_bounds(res, lb, ub, lo_side)
+            got = solve_lp_resume(form, lb, ub, res.factors)
+            assert got is not None
+            _same_result(got, solve_lp_np(
+                c, A, bl, bu, ub, lb=lb,
+                warm_start=(res.basis, res.at_upper)))
+            compared += 1
+            if got.status != OPTIMAL or _child_bounds(
+                    got, lb, ub, lo_side) is None:
+                break
+            res = got
+    assert compared >= 3
+
+
+def test_siblings_share_a_parent_untouched():
+    """Both children of one node resume from its one set of factors; the
+    first child's pivots write none of the parent's arrays."""
+    tried = 0
+    for (c, A, bl, bu, ub), parent in _branching_lps(range(30)):
+        f = parent.factors
+        before = [a.copy() for a in (f.basis, f.at_upper, f.Binv, f.y, f.d)]
+        form = prepare_lp(c, A, bl, bu)
+        for lo_side in (True, False):
+            lb2, ub2 = _child_bounds(parent, np.zeros(len(ub)), ub, lo_side)
+            _same_result(solve_lp_resume(form, lb2, ub2, f),
+                         solve_lp_np(c, A, bl, bu, ub2, lb=lb2,
+                                     warm_start=parent.warm))
+        for a, b in zip((f.basis, f.at_upper, f.Binv, f.y, f.d), before):
+            assert np.array_equal(a, b)
+        tried += 1
+    assert tried >= 5
+
+
+@pytest.mark.parametrize("max_iters", [5000, 2, 3],
+                         ids=["optimal", "cut-at-2", "cut-at-3"])
+def test_final_factors_are_fresh(max_iters):
+    """The answer and the carried factors are those of a fresh
+    factorization of the final basis, bit for bit, also where the pivot
+    limit cuts the solve on rank-1-updated factors."""
+    for seed in range(12):
+        c, A, bl, bu, ub = _random_lp(seed)
+        res = solve_lp_np(c, A, bl, bu, ub, max_iters=max_iters)
+        form = prepare_lp(c, A, bl, bu)
+        f = res.factors
+        Binv = np.linalg.inv(form.A[:, f.basis])
+        y = Binv.T @ form.cf[f.basis]
+        d = form.cf - form.A.T @ y
+        d[f.basis] = 0.0
+        assert np.array_equal(f.Binv, Binv)
+        assert np.array_equal(f.y, y) and np.array_equal(f.d, d)
+        assert np.array_equal(res.y, y * form.scale)
+
+
+def test_resume_returns_none_on_an_empty_box():
+    c, A, bl, bu, ub = _random_lp(2)
+    parent = solve_lp_np(c, A, bl, bu, ub)
+    lb = np.zeros(len(ub))
+    lb[0] = ub[0] + 1.0
+    assert solve_lp_resume(prepare_lp(c, A, bl, bu), lb, ub,
+                           parent.factors) is None
 
 
 if HAS_HYPOTHESIS:
