@@ -8,8 +8,11 @@ One chip, in order:
 
   relation  a TPC-H ``lineitem``-like relation (paper Table 2 columns)
             made from ``--seed``;
-  build     ``PackageQueryEngine(...).partition()``: DLV column scans and
-            the segstats kernel run on the chip;
+  build     ``PackageQueryEngine(...).partition()``: the segstats kernel
+            and the DLV column scans of wide length classes (a round's
+            thousands of short partitions) run on the chip; lone long
+            spans (each layer's first rounds, Algorithm 7's samples) are
+            cut on the host by the jump scan;
   kernels   segstats, pricing and BFRT select against their host twins
             at real widths, each lowered to a Mosaic ``tpu_custom_call``;
   default   three ``Q2_TPCH`` queries (hardness 1, 3, 5) through

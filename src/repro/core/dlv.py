@@ -1,11 +1,15 @@
 """Dynamic Low Variance partitioning — paper §3 (Algorithms 5, 6, 7).
 
 1-D DLV is a running-variance reset scan over sorted attribute values
-(Algorithm 5) — a jitted *segmented* ``lax.scan`` (tiny carry, O(n)) that
-processes many partitions' concatenated spans in one launch, with
-Kahan-compensated accumulators so the cut decisions stay identical to a
-float64 host reference even when jax runs without x64 (the dtype is derived
-from the input, never hard-coded).
+(Algorithm 5), run over many partitions' concatenated spans at once in one
+of two exact forms, chosen per length class by cost: a *column* scan that
+steps all spans of a class row by row together (on a TPU the jitted
+``lax.scan``, tiny carry, with Kahan-compensated accumulators so the cut
+decisions stay identical to a float64 host reference even when jax runs
+without x64; elsewhere its numpy twin), or the host's vectorized *jump*
+scan from cut to cut of one span.  On a TPU the chip takes wide classes of
+short spans (a frontier round's thousands of small partitions); lone long
+spans (a layer's first round, Algorithm 7's samples) are cut on the host.
 
 DLV (Algorithm 6) is divisive hierarchical clustering keyed by *total
 variance* (|P| * max_j var_j) with bounding variance beta = c_j sigma^2/d_f^2
@@ -205,6 +209,63 @@ _COL_BUDGET = 1 << 23        # max padded elements per scan launch
 _BATCH_MIN_COLS = 16         # below this, per-segment jump scan wins
 _MAX_COLS = 1024             # numpy row-step width sweet spot
 
+# The TPU side of the cost model, in seconds, measured on one TPU v5e
+# host with float64 on.  A device column scan launch of C padded rows and
+# m padded columns costs LAUNCH + C * ROW1 for one column (the one-column
+# scan steps ~16x slower than a two-column one) and LAUNCH + C * (ROW +
+# m * ELEM) otherwise, ELEM being the host gather and transfer of a
+# round's wide classes (1024 x 8192, 2048 x 4096).  The host jump scan of
+# one segment costs C * JUMP_ROW + JUMP_SEG, fitted over every class of
+# three 10^7-row TPC-H builds at d_f = 100.
+_TPU_LAUNCH_S = 1.5e-3
+_TPU_ROW1_S = 6.85e-6
+_TPU_ROW_S = 4e-7
+_TPU_ELEM_S = 4e-8
+_JUMP_ROW_S = 1.5e-8
+_JUMP_SEG_S = 1.8e-4
+
+
+def _cut_plan(Ls: np.ndarray, pitch: int, device: bool):
+    """The forms ``_seg_cuts`` cuts its segments with: yields
+    ``(segments, rows, column_scan)`` per length class.
+
+    Classes are pow2 row classes on a TPU (the column scan is the jitted
+    device scan, whose jit shapes stay bounded) and groups of sorted
+    lengths within 2x elsewhere (the column scan is the numpy twin, whose
+    shapes are free).  Each class takes the column scan or the
+    per-segment jump scan, whichever the platform's cost model finds
+    cheaper: off the TPU from its columns and ``pitch``, on a TPU from
+    its padded rows and columns (the constants above)."""
+    if device:
+        classes = np.fromiter((_pad_rows(int(l)) for l in Ls), np.int64,
+                              len(Ls))
+        for C in np.unique(classes):
+            C = int(C)
+            segs = np.flatnonzero(classes == C)
+            max_cols = max(1, _COL_BUDGET // C)
+            for a in range(0, len(segs), max_cols):
+                sub = segs[a:a + max_cols]
+                m = 1 << int(len(sub) - 1).bit_length()   # padded columns
+                step = _TPU_ROW1_S if m == 1 else _TPU_ROW_S + m * _TPU_ELEM_S
+                scan = _TPU_LAUNCH_S + C * step
+                jump = len(sub) * (C * _JUMP_ROW_S + _JUMP_SEG_S)
+                yield sub, C, scan < jump
+        return
+    ord_len = np.argsort(Ls, kind="stable")
+    i = 0
+    while i < len(ord_len):
+        L0 = int(Ls[ord_len[i]])
+        j = i + 1
+        while (j < len(ord_len) and j - i < _MAX_COLS
+               and Ls[ord_len[j]] <= max(2 * L0, L0 + 64)):
+            j += 1
+        group = ord_len[i:j]
+        i = j
+        cols = len(group)
+        # jump cost ~ cols*rows/pitch window ops; row scan ~ rows steps
+        yield group, int(Ls[group[-1]]), not (
+            cols < _BATCH_MIN_COLS or cols < max(1, pitch) // 2)
+
 
 def _batch_cols(cuts, vals_shifted, starts, Ls, beta_seg, sub,
                 scanner=None) -> None:
@@ -255,20 +316,27 @@ def _snap_cuts_to_run_starts(vals: np.ndarray, cuts: np.ndarray,
 
 
 def _seg_cuts(vals_shifted: np.ndarray, Ls: np.ndarray,
-              beta_seg: np.ndarray, *, pitch: int = 256) -> np.ndarray:
+              beta_seg: np.ndarray, *, pitch: int = 256,
+              spans: Optional[SpanLog] = None) -> np.ndarray:
     """Delimiters for many independent sorted segments, concatenated in
     ``vals_shifted`` with lengths ``Ls`` (each centered on its own mean).
 
-    Host path: segments grouped by sorted length (<= 2x padding, no jit so
-    shapes are free); a group runs as ONE column-parallel row-step scan
-    when wide enough, otherwise each segment uses the exact vectorized
-    jump scan — a 10^7-row round-1 segment costs ~one vectorized pass, not
-    10^7 sequential steps.  ``pitch`` is the expected inter-cut distance
-    (~d_f): the cost model — row scan ~ rows, jump scan ~ cols*rows/pitch
-    — picks the cheaper form per group.  TPU path: pow2 length classes
-    (bounded jit shapes) through the jitted Kahan column scan.  All paths
-    end with cuts snapped to equal-value run starts (split-tree/gid
-    consistency on ties).
+    ``_cut_plan`` groups the segments into length classes and gives each
+    class the cheaper of two exact forms: ONE column-parallel row-step
+    scan over the class, or the vectorized jump scan per segment (a
+    10^7-row round-1 segment costs ~one vectorized pass, not 10^7
+    sequential steps).  Off the TPU the column scan is the numpy twin
+    over <= 2x length groups, and ``pitch``, the expected inter-cut
+    distance (~d_f), steers the choice.  On a TPU it is the jitted Kahan
+    scan over pow2 length classes, which the chip takes only for wide
+    classes of short segments (a frontier round's thousands of ~10^3-row
+    spans); lone long segments and narrow classes (a layer's first
+    rounds, Algorithm 7's sample columns) go to the jump scan on the
+    host, which cuts a row in ~15 ns where the chip's one-column scan
+    steps one in ~7 us.  ``spans`` counts the rows each side cut
+    (``dlv_cut_rows_device``, ``dlv_cut_rows_host``).  All forms end with
+    cuts snapped to equal-value run starts (split-tree/gid consistency on
+    ties).
     """
     n = len(vals_shifted)
     Ls = np.asarray(Ls, np.int64)
@@ -278,37 +346,21 @@ def _seg_cuts(vals_shifted: np.ndarray, Ls: np.ndarray,
     starts = np.concatenate([[0], np.cumsum(Ls)[:-1]])
     beta_seg = np.asarray(beta_seg, np.float64)
     from repro.kernels.ops import on_tpu
-    if on_tpu():
-        classes = np.fromiter((_pad_rows(int(l)) for l in Ls), np.int64,
-                              len(Ls))
-        for C in np.unique(classes):
-            segs = np.flatnonzero(classes == C)
-            max_cols = max(1, _COL_BUDGET // int(C))
-            for a in range(0, len(segs), max_cols):
-                sub = segs[a:a + max_cols]
-                _batch_cols(cuts, vals_shifted, starts, Ls, beta_seg, sub,
-                            scanner=_device_scanner(int(C)))
-        return _snap_cuts_to_run_starts(vals_shifted, cuts, starts)
-
-    ord_len = np.argsort(Ls, kind="stable")
-    i = 0
-    while i < len(ord_len):
-        L0 = int(Ls[ord_len[i]])
-        j = i + 1
-        while (j < len(ord_len) and j - i < _MAX_COLS
-               and Ls[ord_len[j]] <= max(2 * L0, L0 + 64)):
-            j += 1
-        group = ord_len[i:j]
-        i = j
-        cols = len(group)
-        # jump cost ~ cols*rows/pitch window ops; row scan ~ rows steps
-        if cols < _BATCH_MIN_COLS or cols < max(1, pitch) // 2:
+    device = on_tpu()
+    device_rows = 0
+    for group, rows, column_scan in _cut_plan(Ls, pitch, device):
+        if column_scan:
+            _batch_cols(cuts, vals_shifted, starts, Ls, beta_seg, group,
+                        scanner=_device_scanner(rows) if device else None)
+            device_rows += int(Ls[group].sum()) if device else 0
+        else:
             for s in group:
                 a = starts[s]
                 cuts[a:a + Ls[s]] = _jump_scan_np(
                     vals_shifted[a:a + Ls[s]], float(beta_seg[s]))
-        else:
-            _batch_cols(cuts, vals_shifted, starts, Ls, beta_seg, group)
+    if spans is not None:
+        spans.add("dlv_cut_rows_device", device_rows)
+        spans.add("dlv_cut_rows_host", n - device_rows)
     return _snap_cuts_to_run_starts(vals_shifted, cuts, starts)
 
 
@@ -400,12 +452,14 @@ def ratio_score(values: np.ndarray, gid: np.ndarray, *,
 
 def get_scale_factors(X: np.ndarray, d_f: int, *, sample: int = 10_000,
                       eps: float = 1e-9, max_steps: int = 60,
-                      rng: Optional[np.random.Generator] = None) -> np.ndarray:
+                      rng: Optional[np.random.Generator] = None,
+                      spans: Optional[SpanLog] = None) -> np.ndarray:
     """Algorithm 7: per-attribute constants c_j with beta = c_j sigma^2/d_f^2.
 
     All attributes' binary searches advance in lock-step: each iteration
-    runs ONE column-parallel scan over the (sample, k) sorted matrix with
+    runs ONE segmented scan over the k sorted sample columns with
     per-attribute betas, instead of k independent scan sequences.
+    ``spans`` receives the scans' row counters (``_seg_cuts``).
     """
     rng = rng or np.random.default_rng(0)
     n, k = X.shape
@@ -428,7 +482,8 @@ def get_scale_factors(X: np.ndarray, d_f: int, *, sample: int = 10_000,
             break
         beta = np.where(run, 0.5 * (lo + hi), beta)
         B = np.where(run, beta, np.inf)          # frozen columns never cut
-        p = _seg_cuts(vflat, Lk, B).reshape(k, take).sum(axis=1) + 1
+        p = _seg_cuts(vflat, Lk, B, spans=spans).reshape(k, take).sum(
+            axis=1) + 1
         searching &= ~(run & (p == target))      # converged exactly
         hi = np.where(run & (p < target), beta, hi)
         lo = np.where(run & (p > target), beta, lo)
@@ -609,14 +664,15 @@ def dlv_rounds(X: np.ndarray, d_f: int, *, c: Optional[np.ndarray] = None,
     (Algorithm 7's scale factors), each round's ``dlv.sort`` (gather and
     per-span sort), ``dlv.cuts`` (delimiter scans with their beta/4
     retries) and ``dlv.stats`` (children's stats), the
-    ``build.finalize`` tail and the ``dlv_rounds`` counter.
+    ``build.finalize`` tail, the ``dlv_rounds`` counter and the rows each
+    form cut (``dlv_cut_rows_device``, ``dlv_cut_rows_host``).
     """
     X = np.asarray(X, np.float64)
     n, k = X.shape
     target = min_groups if min_groups is not None else max(1, n // d_f)
     if c is None:
         with span(spans, "dlv.scale"):
-            c = get_scale_factors(X, d_f, rng=rng)
+            c = get_scale_factors(X, d_f, rng=rng, spans=spans)
     gshift = X.mean(axis=0)
 
     order = np.arange(n)
@@ -681,7 +737,7 @@ def dlv_rounds(X: np.ndarray, d_f: int, *, c: Optional[np.ndarray] = None,
             reset = np.zeros(total, bool)
             reset[seg_off[:-1]] = True
             vs = vals_s - np.repeat(mean_sel, Ls)
-            cuts = _seg_cuts(vs, Ls, beta_sel, pitch=d_f)
+            cuts = _seg_cuts(vs, Ls, beta_sel, pitch=d_f, spans=spans)
 
             # segments that produced no delimiter retry with beta/4 (the heap
             # build's rule); all-equal segments can never split -> frozen
@@ -695,7 +751,7 @@ def dlv_rounds(X: np.ndarray, d_f: int, *, c: Optional[np.ndarray] = None,
                 fmask[fail] = True
                 elm = fmask[segid]
                 cuts[elm] = _seg_cuts(vs[elm], Ls[fail], beta_sel[fail],
-                                      pitch=d_f)
+                                      pitch=d_f, spans=spans)
                 ncuts = np.bincount(segid[cuts], minlength=nseg)
                 fail = np.flatnonzero((ncuts == 0) & ~alleq)
                 tries += 1

@@ -183,3 +183,89 @@ def test_ratio_score_sparse_and_negative_ids():
     assert 0.0 <= zw <= 1.0 + 1e-12
     assert zw == pytest.approx(ratio_score(vals, dense, weighted=True),
                                rel=1e-12)
+
+
+# ------------------------------------------- cut plan on a TPU (forced here)
+
+
+def _segments(rng, lens):
+    """Sorted segments, each centered on its own mean, with the build's
+    beta at d_f = 100 (scale factor 13.5)."""
+    segs = [np.sort(rng.normal(rng.uniform(-5, 5), rng.uniform(0.5, 2), L))
+            for L in lens]
+    beta = np.array([13.5 * np.var(s) / 100 ** 2 for s in segs])
+    return np.concatenate([s - s.mean() for s in segs]), beta
+
+
+@pytest.fixture
+def tpu_plan(monkeypatch):
+    """``_seg_cuts`` planning as on a TPU; its jitted device scan then runs
+    on this backend."""
+    import repro.kernels.ops as ops
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("lens", [
+    [200_000],
+    [900] * 40,
+    [17, 2500, 300, 41] * 8,
+    [150_000] + [700] * 600,
+], ids=["long", "equal", "mixed", "long_beside_short"])
+def test_seg_cuts_tpu_plan_matches_reference(tpu_plan, lens):
+    """With the TPU's plan, whichever form each class takes, the cuts are
+    the float64 reference's, segment by segment."""
+    from repro.core.dlv import _dlv_scan_np, _seg_cuts
+    from repro.core.spans import SpanLog
+    vals, beta = _segments(np.random.default_rng(len(lens)), lens)
+    log = SpanLog()
+    got = _seg_cuts(vals, np.array(lens), beta, pitch=100, spans=log)
+    starts = np.concatenate([[0], np.cumsum(lens)])
+    for s, b in enumerate(beta):
+        a, e = starts[s], starts[s + 1]
+        np.testing.assert_array_equal(got[a:e], _dlv_scan_np(vals[a:e], b))
+    assert got.sum() > len(lens)                 # the cases actually split
+    assert (log.counters["dlv_cut_rows_device"]
+            + log.counters["dlv_cut_rows_host"]) == sum(lens)
+    if len(lens) > 600:                          # both forms ran
+        assert log.counters["dlv_cut_rows_device"] == 600 * 700
+        assert log.counters["dlv_cut_rows_host"] == 150_000
+
+
+def test_tpu_cut_plan_by_shape():
+    """On a TPU a lone long segment goes to the host jump scan and a wide
+    class of short segments to the device column scan, in pow2 classes."""
+    from repro.core.dlv import _cut_plan
+    [(segs, rows, column_scan)] = _cut_plan(np.array([10_000_000]), 100,
+                                            True)
+    assert list(segs) == [0] and rows == 1 << 24 and not column_scan
+    [(segs, rows, column_scan)] = _cut_plan(np.array([900] * 2000), 100,
+                                            True)
+    assert len(segs) == 2000 and rows == 1024 and column_scan
+    plan = list(_cut_plan(np.array([3000, 200_000, 600, 5000]), 100, True))
+    assert [(list(s), r) for s, r, _ in plan] == [
+        ([2], 1024), ([0], 4096), ([3], 8192), ([1], 262144)]
+
+
+def test_host_cut_plan_by_shape():
+    """Off the TPU: sorted lengths grouped within 2x, each group a numpy
+    column scan when at least max(16, pitch // 2) wide, else jump scans."""
+    from repro.core.dlv import _cut_plan
+    [(segs, _, column_scan)] = _cut_plan(np.array([900] * 40), 256, False)
+    assert len(segs) == 40 and not column_scan
+    [(segs, _, column_scan)] = _cut_plan(np.array([900] * 60), 100, False)
+    assert len(segs) == 60 and column_scan
+    groups = [(sorted(s), c) for s, _, c in
+              _cut_plan(np.array([100] * 20 + [250] + [10_000]), 8, False)]
+    assert groups == [(list(range(20)), True), ([20], False), ([21], False)]
+
+
+def test_seg_cuts_counts_rows_on_the_build_log():
+    """``dlv_rounds`` hands its log to every ``_seg_cuts`` call: the rows
+    each side cut add up to the rows of every scan; off the TPU all are
+    the host's."""
+    from repro.core.spans import SpanLog
+    X = np.random.default_rng(5).normal(size=(3000, 3))
+    log = SpanLog()
+    dlv(X, d_f=10, spans=log)
+    assert log.counters["dlv_cut_rows_device"] == 0
+    assert log.counters["dlv_cut_rows_host"] >= 3000 + 3 * 3000
