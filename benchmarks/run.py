@@ -24,7 +24,7 @@ use_compile_cache()
 from benchmarks import (ablations, analysis_bench, batch_lp, cache_bench,
                         concurrency_bench, dual_reducer_bench, grid,
                         infeasibility, partitioning, pds_scaling,
-                        ratio_score, roofline, scaling, warm_start)
+                        ratio_score, scaling, warm_start)
 from benchmarks.common import ROWS
 
 MODULES = {
@@ -40,7 +40,6 @@ MODULES = {
     "cache": cache_bench,
     "concurrency": concurrency_bench,
     "batch_lp": batch_lp,
-    "roofline": roofline,
     "analysis": analysis_bench,
 }
 

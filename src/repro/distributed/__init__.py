@@ -1,3 +1,1 @@
-from repro.distributed.sharding import ShardingRules, make_rules
-
-__all__ = ["ShardingRules", "make_rules"]
+"""HLO analysis of compiled programs (collectives, while trip counts)."""

@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.attention import flash_attention
 from repro.kernels.bfrt import bfrt_histogram, bfrt_select
 from repro.kernels.pricing import pricing
 from repro.kernels.segstats import (segment_stats, segment_stats_np,
@@ -63,22 +62,6 @@ def segment_stats_auto(vals, ids, num_groups):
     return segment_stats_np(vals, ids, num_groups)
 
 
-def flash_attention_op(q, k, v, *, num_kv_heads=None, **kw):
-    """q: (B, S, H, d); k/v: (B, S, KV, d).  GQA expansion then kernel."""
-    B, S, H, d = q.shape
-    KV = k.shape[2]
-    if KV != H:
-        rep = H // KV
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(B * H, S, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(B * H, S, d)
-    o = flash_attention(qf, kf, vf, interpret=interpret_kernels(), **kw)
-    return o.reshape(B, H, S, d).transpose(0, 2, 1, 3)
-
-
 __all__ = ["pricing_op", "bfrt_select_op", "segment_stats_op",
-           "segment_stats_auto", "segment_stats_np", "flash_attention_op",
-           "bfrt_histogram", "segstats_partials", "on_tpu",
-           "interpret_kernels"]
+           "segment_stats_auto", "segment_stats_np", "bfrt_histogram",
+           "segstats_partials", "on_tpu", "interpret_kernels"]

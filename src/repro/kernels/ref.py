@@ -1,9 +1,6 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
 from __future__ import annotations
 
-import math
-
-import jax
 import jax.numpy as jnp
 
 
@@ -63,19 +60,3 @@ def segment_stats_ref(vals, ids, num_groups):
         vals * vals)
     return counts, sums, sqs
 
-
-def attention_ref(q, k, v, *, causal=True, window=0):
-    """Oracle for kernels.attention.flash_attention. q/k/v: (BH, S, d)."""
-    BH, S, d = q.shape
-    s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) / math.sqrt(d)
-    qp = jnp.arange(S)[:, None]
-    kp = jnp.arange(S)[None, :]
-    mask = jnp.ones((S, S), bool)
-    if causal:
-        mask = qp >= kp
-    if window > 0:
-        mask = mask & ((qp - kp) < window)
-    s = jnp.where(mask[None], s, -1e30)
-    w = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", w, v.astype(jnp.float32)).astype(q.dtype)

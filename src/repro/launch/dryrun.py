@@ -2,89 +2,23 @@ import os
 os.environ["XLA_FLAGS"] = (os.environ.get("_DRYRUN_EXTRA_XLA", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 
-"""Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
+"""Multi-pod dry-run of the distributed package-query step.
 
 The two lines above MUST run before any other import (jax locks the device
 count on first init).  512 host devices back both the 16x16 single-pod mesh
 and the 2x16x16 multi-pod mesh.
 
 Usage:
-  python -m repro.launch.dryrun --arch smollm-135m --shape train_4k
-  python -m repro.launch.dryrun --all [--multi-pod] [--out results/dryrun]
-Outputs one JSON per cell with memory analysis, cost analysis, collective
-bytes (while-aware), and corrected dot-FLOPs for the roofline.
+  python -m repro.launch.dryrun --pq [--multi-pod | --both-meshes]
+                                [--out results/dryrun]
+Writes one JSON per mesh with the step's collective bytes, its byte budget
+and any contract violations.
 """
 import argparse
 import json
-import time
 import traceback
 
-import jax
-
-from repro.configs import ARCH_IDS, SHAPES, get_config, shape_applicable
-from repro.distributed.hlo_analysis import collective_bytes, hlo_stats
 from repro.launch.mesh import make_production_mesh
-from repro.launch.specs import build_cell
-
-
-def _mem_dict(mem) -> dict:
-    out = {}
-    for attr in ("argument_size_in_bytes", "output_size_in_bytes",
-                 "temp_size_in_bytes", "generated_code_size_in_bytes",
-                 "alias_size_in_bytes", "peak_memory_in_bytes"):
-        try:
-            v = getattr(mem, attr)
-            out[attr] = int(v() if callable(v) else v)
-        except (AttributeError, TypeError, ValueError, RuntimeError):
-            pass  # field absent on this jaxlib's MemoryAnalysis
-    if not out:
-        out["repr"] = str(mem)
-    return out
-
-
-def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
-             save_hlo: str = "") -> dict:
-    cfg = get_config(arch)
-    ok, why = shape_applicable(cfg, SHAPES[shape_name])
-    mesh_name = "2x16x16" if multi_pod else "16x16"
-    rec = {"arch": arch, "shape": shape_name, "mesh": mesh_name}
-    if not ok:
-        rec.update(status="SKIP", reason=why)
-        return rec
-    t0 = time.time()
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    with mesh:
-        cell = build_cell(arch, shape_name, mesh)
-        lowered = jax.jit(cell.fn, in_shardings=cell.in_shardings,
-                          out_shardings=cell.out_shardings,
-                          donate_argnums=cell.donate_argnums,
-                          ).lower(*cell.args)
-        t_lower = time.time() - t0
-        compiled = lowered.compile()
-        t_compile = time.time() - t0 - t_lower
-        mem = compiled.memory_analysis()
-        cost = compiled.cost_analysis()
-        hlo = compiled.as_text()
-        coll = collective_bytes(hlo)
-        st = hlo_stats(hlo)
-    if save_hlo:
-        with open(save_hlo, "w") as f:
-            f.write(hlo)
-    rec.update(
-        status="OK",
-        lower_s=round(t_lower, 1),
-        compile_s=round(t_compile, 1),
-        n_devices=int(mesh.size),
-        memory=_mem_dict(mem),
-        cost={k: float(v) for k, v in cost.items()
-              if k in ("flops", "bytes accessed", "transcendentals")},
-        collectives={k: float(v) for k, v in coll.merged().items()},
-        collective_counts=dict(coll.count_by_kind),
-        dot_flops=st.flops,
-        dot_bytes=st.dot_bytes,
-        instr_bytes=st.instr_bytes,
-    )
-    return rec
 
 
 def run_pq_cell(*, multi_pod: bool, n: int = 1 << 24) -> dict:
@@ -121,87 +55,41 @@ def run_pq_cell(*, multi_pod: bool, n: int = 1 << 24) -> dict:
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
-    ap.add_argument("--shape", default=None)
-    ap.add_argument("--all", action="store_true")
     ap.add_argument("--pq", action="store_true",
                     help="dry-run the distributed package-query step")
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--out", default="results/dryrun")
-    ap.add_argument("--save-hlo", default="")
-    ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
-
-    if args.pq:
-        os.makedirs(args.out, exist_ok=True)
-        rc = 0
-        for mp in ([False, True] if args.both_meshes else [args.multi_pod]):
-            mesh_name = "2x16x16" if mp else "16x16"
-            try:
-                rec = run_pq_cell(multi_pod=mp)
-            except (ValueError, TypeError, KeyError, RuntimeError,
-                    NotImplementedError, OSError) as e:
-                # XlaRuntimeError subclasses RuntimeError; anything else
-                # (assertion, keyboard interrupt) should still crash loudly
-                rec = {"arch": "pq_step", "mesh": mesh_name, "status": "FAIL",
-                       "error": f"{type(e).__name__}: {e}",
-                       "traceback": traceback.format_exc()[-4000:]}
-            if rec["status"] != "OK":
-                rc = 1
-            with open(os.path.join(args.out,
-                                   f"pq_step__{mesh_name}.json"), "w") as f:
-                json.dump(rec, f, indent=1)
-            print(f"[dryrun] pq_step {mesh_name}: {rec['status']} "
-                  + rec.get("error", "")[:200], flush=True)
-            for v in rec.get("violations", ()):
-                print(f"  {v}", flush=True)
-            if rec["status"] in ("OK", "CONTRACT_FAIL"):
-                print(f"  coll_bytes/dev={rec['collectives'].get('total', 0):.3e}"
-                      f" budget_used={rec['budget_used_frac']:.2f}")
-        return rc
-
-    cells = []
-    archs = [args.arch] if args.arch else list(ARCH_IDS)
-    shapes = [args.shape] if args.shape else list(SHAPES)
-    meshes = [False, True] if args.both_meshes else [args.multi_pod]
-    for mp in meshes:
-        for a in archs:
-            for s in shapes:
-                cells.append((a, s, mp))
+    if not args.pq:
+        ap.error("nothing to dry-run: pass --pq")
 
     os.makedirs(args.out, exist_ok=True)
-    failures = 0
-    for a, s, mp in cells:
+    rc = 0
+    for mp in ([False, True] if args.both_meshes else [args.multi_pod]):
         mesh_name = "2x16x16" if mp else "16x16"
-        path = os.path.join(args.out, f"{a}__{s}__{mesh_name}.json")
-        if args.skip_existing and os.path.exists(path):
-            print(f"[dryrun] {a} {s} {mesh_name}: exists, skipping")
-            continue
-        print(f"[dryrun] {a} {s} {mesh_name} ...", flush=True)
         try:
-            rec = run_cell(a, s, multi_pod=mp, save_hlo=args.save_hlo)
+            rec = run_pq_cell(multi_pod=mp)
         except (ValueError, TypeError, KeyError, RuntimeError,
                 NotImplementedError, OSError) as e:
-            rec = {"arch": a, "shape": s, "mesh": mesh_name,
-                   "status": "FAIL", "error": f"{type(e).__name__}: {e}",
+            # XlaRuntimeError subclasses RuntimeError; anything else
+            # (assertion, keyboard interrupt) should still crash loudly
+            rec = {"arch": "pq_step", "mesh": mesh_name, "status": "FAIL",
+                   "error": f"{type(e).__name__}: {e}",
                    "traceback": traceback.format_exc()[-4000:]}
-            failures += 1
-        with open(path, "w") as f:
+        if rec["status"] != "OK":
+            rc = 1
+        with open(os.path.join(args.out,
+                               f"pq_step__{mesh_name}.json"), "w") as f:
             json.dump(rec, f, indent=1)
-        msg = rec["status"]
-        if rec["status"] == "OK":
-            per_dev = rec["memory"].get("argument_size_in_bytes", 0)
-            msg += (f" compile={rec['compile_s']}s"
-                    f" arg_bytes/dev={per_dev/2**30:.2f}GiB"
-                    f" dot_flops/dev={rec['dot_flops']:.3e}"
-                    f" coll_bytes/dev={rec['collectives'].get('total', 0):.3e}")
-        elif rec["status"] == "FAIL":
-            msg += " " + rec["error"][:200]
-        print(f"[dryrun] {a} {s} {mesh_name}: {msg}", flush=True)
-    print(f"[dryrun] done, {failures} failures")
-    return 1 if failures else 0
-
+        print(f"[dryrun] pq_step {mesh_name}: {rec['status']} "
+              + rec.get("error", "")[:200], flush=True)
+        for v in rec.get("violations", ()):
+            print(f"  {v}", flush=True)
+        if rec["status"] in ("OK", "CONTRACT_FAIL"):
+            print(f"  coll_bytes/dev={rec['collectives'].get('total', 0):.3e}"
+                  f" budget_used={rec['budget_used_frac']:.2f}")
+    return rc
 
 if __name__ == "__main__":
     raise SystemExit(main())

@@ -1,5 +1,4 @@
-from repro.runtime.coordinator import Coordinator, WorkerState
 from repro.runtime import faults
 from repro.runtime import racecheck
 
-__all__ = ["Coordinator", "WorkerState", "faults", "racecheck"]
+__all__ = ["faults", "racecheck"]

@@ -1,4 +1,3 @@
 from repro.serving.scheduler import PackageScheduler, Request
-from repro.serving.engine import ServingEngine
 
-__all__ = ["PackageScheduler", "Request", "ServingEngine"]
+__all__ = ["PackageScheduler", "Request"]

@@ -2,8 +2,8 @@
 the serving tier.
 
 Every scheduling tick, the waiting-request pool is a relation (one row per
-request: priority, prefill FLOPs, KV-cache bytes, decode length estimate)
-and batch formation IS a package query:
+request: priority, KV-cache bytes, prefill FLOPs) and batch formation IS a
+package query:
 
     SELECT PACKAGE(*) FROM queue REPEAT 0
     SUCH THAT COUNT(P.*) <= max_batch
@@ -15,13 +15,14 @@ solved with Dual Reducer (sub-second at 10^5+ queued requests, matching the
 paper's interactivity requirement).  This replaces greedy FCFS admission
 with a globally optimal knapsack per tick.
 
-The per-request feature table is maintained incrementally: columns are
-appended once at ``submit`` (kv_bytes / prefill_flops are computed exactly
-once per request) and mask-compacted when requests are admitted, so a tick
-over a large pool never rebuilds python-side lists.  Each tick solves
-under a ``guard.SolveBudget`` deadline and contains any solver exception,
-so the serving loop inherits the never-raise / never-hang contract; the
-last ``guard.SolveReport`` is kept on ``last_report`` for observability.
+A request's costs are numbers set by the caller, who knows the model it
+serves.  The per-request feature table is maintained incrementally:
+columns are appended once at ``submit`` and mask-compacted when requests
+are admitted, so a tick over a large pool never rebuilds python-side
+lists.  Each tick solves under a ``guard.SolveBudget`` deadline and
+contains any solver exception, so the serving loop inherits the
+never-raise / never-hang contract; the last ``guard.SolveReport`` is kept
+on ``last_report`` for observability.
 """
 from __future__ import annotations
 
@@ -38,19 +39,13 @@ from repro.core.paql import Constraint, PackageQuery
 
 @dataclasses.dataclass
 class Request:
+    """A waiting request.  Its costs are in the units of the scheduler's
+    budgets: ``kv_bytes`` against ``hbm_budget_bytes``, ``prefill_flops``
+    against ``flop_budget``."""
     rid: int
-    prompt_tokens: int
-    max_new_tokens: int
     priority: float
-
-    def kv_bytes(self, cfg) -> float:
-        per_tok = 2 * 2 * cfg.num_kv_heads * cfg.resolved_head_dim \
-            * cfg.num_layers
-        return float(per_tok * (self.prompt_tokens + self.max_new_tokens))
-
-    def prefill_flops(self, cfg) -> float:
-        n_active = cfg.active_param_count()
-        return float(2 * n_active * self.prompt_tokens)
+    kv_bytes: float
+    prefill_flops: float
 
 
 _COLUMNS = ("priority", "kv_bytes", "prefill_flops")
@@ -112,10 +107,9 @@ class PackageScheduler:
                       "_admitted_total": "_lock", "last_report": "_lock",
                       "rng": "_tick_lock"}
 
-    def __init__(self, cfg, *, hbm_budget_bytes: float,
+    def __init__(self, *, hbm_budget_bytes: float,
                  flop_budget: float, max_batch: int = 64, seed: int = 0,
                  time_limit_s: float = 5.0, wave_width: int = 8):
-        self.cfg = cfg
         self.hbm_budget = hbm_budget_bytes
         self.flop_budget = flop_budget
         self.max_batch = max_batch
@@ -132,8 +126,8 @@ class PackageScheduler:
     def submit(self, req: Request):
         with self._lock:
             self.queue.append(req)
-            self._store.append(req.priority, req.kv_bytes(self.cfg),
-                               req.prefill_flops(self.cfg))
+            self._store.append(req.priority, req.kv_bytes,
+                               req.prefill_flops)
 
     def tick(self) -> List[Request]:
         """Admit the optimal batch; admitted requests leave the queue.

@@ -22,6 +22,21 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture(scope="session")
+def lm_request():
+    """Build an admission ``Request`` from token counts, with the costs of
+    a qwen2-1.5b-sized model: a KV cache of 28,672 bytes a token (K and V,
+    2 bytes, 2 KV heads of 128, 28 layers) over prompt and decode, and
+    2 FLOPs per active parameter (1,543,712,768) per prompt token."""
+    from repro.serving import Request
+
+    def make(rid, prompt_tokens, max_new_tokens, priority):
+        return Request(rid, priority,
+                       kv_bytes=28_672.0 * (prompt_tokens + max_new_tokens),
+                       prefill_flops=3_087_425_536.0 * prompt_tokens)
+    return make
+
+
 @pytest.fixture
 def strict_numerics():
     """Fail the test on ANY implicit host<->device transfer and on NaNs

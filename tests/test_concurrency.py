@@ -432,17 +432,15 @@ def test_engine_concurrent_sessions_match_sequential(dataset):
     assert st.hits + st.misses >= len(results)
 
 
-def test_scheduler_concurrent_submits_lose_nothing():
-    from repro.configs import get_config
-    from repro.serving import PackageScheduler, Request
+def test_scheduler_concurrent_submits_lose_nothing(lm_request):
+    from repro.serving import PackageScheduler
 
-    cfg = get_config("qwen2-1.5b")
-    sched = PackageScheduler(cfg, hbm_budget_bytes=2e9, flop_budget=1e14,
+    sched = PackageScheduler(hbm_budget_bytes=2e9, flop_budget=1e14,
                              max_batch=16)
     rng = np.random.default_rng(0)
-    reqs = [[Request(t * 1000 + i, int(rng.integers(16, 256)),
-                     int(rng.integers(16, 128)),
-                     float(rng.uniform(0.01, 1.0))) for i in range(40)]
+    reqs = [[lm_request(t * 1000 + i, int(rng.integers(16, 256)),
+                        int(rng.integers(16, 128)),
+                        float(rng.uniform(0.01, 1.0))) for i in range(40)]
             for t in range(4)]
 
     admitted = []

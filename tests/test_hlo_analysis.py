@@ -117,6 +117,13 @@ def test_shape_bytes():
     assert shape_bytes("token[]") == 0
 
 
+def test_shape_bytes_of_compiled_text_forms():
+    assert shape_bytes("f32[4,8]{1,0}") == 128
+    assert shape_bytes("bf16[2,3]") == 12
+    assert shape_bytes("(f32[2], s32[4])") == 24
+    assert shape_bytes("pred[]") == 1
+
+
 def test_trip_count_recovery_takes_loop_bound():
     # the condition holds two constants (3 and 25); the bound is the max
     assert while_trip_counts(LOOP_HLO) == {"body.1": 25}
@@ -188,6 +195,35 @@ def test_hlo_stats_dot_flops_while_weighted():
     assert st.flops == pytest.approx(10 * 2 * 128 * 32)
     # operand + result bytes: f64[8,32] + f64[32,16] + f64[8,16]
     assert st.dot_bytes == pytest.approx(10 * (2048 + 4096 + 1024))
+
+
+def test_hlo_stats_counts_scanned_dots():
+    """A real compile: a scan of L matmuls counts L dots' FLOPs."""
+    import jax
+    import jax.numpy as jnp
+    L, d = 8, 64
+    W = jnp.ones((L, d, d), jnp.float32)
+    x = jnp.ones((4, d), jnp.float32)
+
+    def f(x, W):
+        return jax.lax.scan(lambda c, w: (c @ w, None), x, W)[0]
+
+    hlo = jax.jit(f).lower(x, W).compile().as_text()
+    st = hlo_stats(hlo)
+    assert st.flops == pytest.approx(L * 2 * 4 * d * d)
+
+
+def test_collective_parser_finds_none_on_one_device():
+    import jax
+    import jax.numpy as jnp
+    x = jnp.ones((8, 8))
+
+    @jax.jit
+    def f(a):
+        return a.sum()
+
+    hlo = f.lower(x).compile().as_text()
+    assert collective_bytes(hlo).total_bytes == 0.0
 
 
 def test_real_lowering_roundtrip():

@@ -5,8 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.ops import (bfrt_select_op, flash_attention_op,
-                               pricing_op, segment_stats_op)
+from repro.kernels.ops import bfrt_select_op, pricing_op, segment_stats_op
 from repro.kernels.ref import bfrt_sequential_ref
 
 
@@ -84,39 +83,3 @@ def test_segstats_builds_representatives(rng):
             np.testing.assert_allclose(means[g], vals[mask].mean(0),
                                        rtol=1e-2, atol=1e-2)
 
-
-@pytest.mark.parametrize("S,blk", [(128, 64), (256, 128)])
-@pytest.mark.parametrize("causal,window", [(True, 0), (True, 64),
-                                           (False, 0)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_kernel(S, blk, causal, window, dtype, rng):
-    B, H, KV, d = 2, 4, 2, 64
-    q = jnp.asarray(rng.normal(size=(B, S, H, d)), dtype)
-    k = jnp.asarray(rng.normal(size=(B, S, KV, d)), dtype)
-    v = jnp.asarray(rng.normal(size=(B, S, KV, d)), dtype)
-    o1 = flash_attention_op(q, k, v, causal=causal, window=window,
-                            block_q=blk, block_k=blk)
-    kx = jnp.repeat(k, H // KV, axis=2)
-    vx = jnp.repeat(v, H // KV, axis=2)
-    qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, d)
-    kf = kx.transpose(0, 2, 1, 3).reshape(B * H, S, d)
-    vf = vx.transpose(0, 2, 1, 3).reshape(B * H, S, d)
-    o2 = ref.attention_ref(qf, kf, vf, causal=causal, window=window)
-    o2 = np.asarray(o2, np.float32).reshape(B, H, S, d).transpose(0, 2, 1, 3)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-3
-    np.testing.assert_allclose(np.asarray(o1, np.float32), o2,
-                               rtol=tol, atol=tol)
-
-
-def test_flash_matches_model_chunked_attention(rng):
-    """Kernel vs the pure-XLA chunked scan used by the dry-run path."""
-    from repro.models.attention import chunked_attention
-    B, S, H, KV, d = 1, 128, 4, 2, 32
-    q = jnp.asarray(rng.normal(size=(B, S, H, d)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, KV, d)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, KV, d)), jnp.float32)
-    pos = jnp.arange(S)
-    o_scan = chunked_attention(q, k, v, pos, pos, causal=True, chunk=32)
-    o_kern = flash_attention_op(q, k, v, causal=True, block_q=64, block_k=64)
-    np.testing.assert_allclose(np.asarray(o_scan), np.asarray(o_kern),
-                               rtol=2e-3, atol=2e-3)
